@@ -32,7 +32,7 @@ import subprocess
 import sys
 import textwrap
 
-from benchmarks.common import emit
+from benchmarks.common import emit, refuse_on_tpu
 
 _REPO = pathlib.Path(__file__).resolve().parent.parent
 
@@ -112,6 +112,7 @@ _CHILD = """
 
 
 def run():
+    refuse_on_tpu("bench_serve_sharded")
     env = {"XLA_FLAGS": "--xla_force_host_platform_device_count=4",
            "PYTHONPATH": str(_REPO / "src"),
            "PATH": os.environ.get("PATH", "/usr/bin:/bin:/usr/local/bin"),

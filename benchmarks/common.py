@@ -22,6 +22,19 @@ def time_fn(fn, *args, iters: int = 5, warmup: int = 2) -> float:
     return times[len(times) // 2]
 
 
+def refuse_on_tpu(name: str) -> None:
+    """Exit non-zero when this process runs on a TPU.
+
+    The caller measures emulated CPU devices in a child process; on a TPU
+    host its rows would be CPU numbers under this host's name.
+    """
+    if jax.default_backend() == "tpu":
+        raise SystemExit(
+            f"{name}: measures 4 emulated CPU devices in a child process, "
+            "so it cannot measure this TPU; refusing to report CPU numbers"
+        )
+
+
 def emit(name: str, us: float, derived: str) -> str:
     row = f"{name},{us:.1f},{derived}"
     print(row)

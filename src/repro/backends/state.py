@@ -110,19 +110,22 @@ class PagedMeta(NamedTuple):
     length: Array
 
 
-# Mantissa budget per quantised storage dtype: scales are 2**(e - BITS)
-# with e from frexp(amax), so payload magnitudes land in [2**(BITS-1),
-# 2**BITS).  int8 uses 7 (round-to-int, clip at 127); fp8 e4m3 uses 8
-# and clips at 240 — the largest multiple of 16 that round-to-nearest
-# maps to itself, which keeps re-encoding a decoded leaf bit-exact.
-_QBITS = {"int8": 7, "fp8": 8}
+# Payload bound per quantised storage dtype: the scale is the power of two
+# 2**e with amax / TOP in [2**(e-1), 2**e), so payload magnitudes land in
+# [TOP/2, TOP).  int8: TOP = 128 (round-to-int, clip at 127).  fp8 e4m3:
+# TOP = 248, the midpoint between e4m3's 240 and 256, so round-to-nearest
+# never rounds a payload up to 256 — the decoded amax stays in the same
+# binade and re-encoding a decoded leaf is bit-exact, while no element is
+# clipped (a [128, 256) payload range would have to clip (240, 256) to 240,
+# twice e4m3's rounding error, on each head's largest moments).
+_QTOP = {"int8": 128.0, "fp8": 248.0}
 
 
 def quantize_leaf(x: Array, n_lead: int, qdtype: str) -> QuantizedLeaf:
     """Quantise one dense state leaf with per-head pow2 scales.
 
-    The scale for each leading-axes index (slot, kv head, …) is
-    ``2**(frexp(amax) - BITS)`` — an exact power of two, so dequantised
+    The scale for each leading-axes index (slot, kv head, …) is the
+    power of two ``2**frexp(amax / TOP)[1]`` — exact, so dequantised
     values re-encode to themselves bit-for-bit: the serve layer may
     decode, splice, and re-encode a slot cache any number of times
     (snapshot handoff, verify rounds) without drift.  Non-finite ``amax``
@@ -139,12 +142,11 @@ def quantize_leaf(x: Array, n_lead: int, qdtype: str) -> QuantizedLeaf:
       ``QuantizedLeaf`` with ``q`` in the storage dtype and fp32
       ``scale`` shaped like ``x`` with size-1 reduced axes.
     """
-    bits = _QBITS[qdtype]
     xf = x.astype(jnp.float32)
     axes = tuple(range(n_lead, x.ndim))
     amax = jnp.max(jnp.abs(xf), axis=axes, keepdims=True)
-    _, e = jnp.frexp(amax)
-    scale = jnp.exp2((e - bits).astype(jnp.float32))
+    _, e = jnp.frexp(amax / _QTOP[qdtype])
+    scale = jnp.exp2(e.astype(jnp.float32))
     scale = jnp.where(jnp.isfinite(amax), scale, amax)
     y = xf / scale
     if qdtype == "int8":

@@ -27,6 +27,7 @@ from __future__ import annotations
 import dataclasses
 
 import jax
+from jax.sharding import PartitionSpec as P
 
 from repro.backends.base import AttentionBackend
 from repro.core import (
@@ -173,15 +174,45 @@ class TaylorBackend(AttentionBackend):
         if not causal:
             return taylor_attention_noncausal(q, k, v, cfg.taylor)
         if self.resolve_impl(cfg) == "pallas":
-            return taylor_attention_kernel_trainable(
-                q, k, v, cfg.taylor, chunk=cfg.attn_chunk,
-                interpret=jax.default_backend() != "tpu", backward="auto",
-            )
+            return self._apply_kernel(q, k, v, cfg)
         if cfg.attn_sharding == "cp":
             o = self._maybe_cp(q, k, v, cfg)
             if o is not None:
                 return o
         return taylor_attention(q, k, v, cfg.taylor, causal=True, chunk=cfg.attn_chunk)
+
+    def _apply_kernel(self, q, k, v, cfg):
+        """The Pallas kernel pair, once per shard under a sharding context.
+
+        The SPMD partitioner cannot split a Mosaic kernel, so on a mesh the
+        call runs inside ``shard_map``: batch over "dp" and heads over "tp"
+        where they divide (GQA groups stay whole: q head i reads kv head
+        i // g, and contiguous head blocks keep that pairing per shard);
+        the sequence is never split — the causal scan needs all of it.
+        """
+        from repro.distributed import api as dist  # noqa: PLC0415 (cycle)
+
+        def kernel(q, k, v):
+            return taylor_attention_kernel_trainable(
+                q, k, v, cfg.taylor, chunk=cfg.attn_chunk,
+                interpret=jax.default_backend() != "tpu", backward="auto",
+            )
+
+        ctx = dist.active()
+        if ctx is None:
+            return kernel(q, k, v)
+        mesh, rules = ctx
+        dp, tp = rules.get("dp"), rules.get("tp")
+        if q.shape[0] % dist.mesh_axis_size(mesh, dp):
+            dp = None
+        if (q.shape[1] % dist.mesh_axis_size(mesh, tp)
+                or k.shape[1] % dist.mesh_axis_size(mesh, tp)):
+            tp = None
+        spec = P(dp, tp, None, None)
+        return jax.shard_map(
+            kernel, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+            check_vma=False,
+        )(q, k, v)
 
     def prefill(self, q, k, v, cfg, n_max):
         n = q.shape[2]
@@ -235,8 +266,6 @@ class TaylorBackend(AttentionBackend):
           ``TaylorState`` of logical ``PartitionSpec`` leaves congruent to
           ``init_cache``'s output.
         """
-        from jax.sharding import PartitionSpec as P  # noqa: PLC0415
-
         from repro.core import TaylorState  # noqa: PLC0415
 
         t = cfg.taylor

@@ -35,7 +35,6 @@ from repro.core.taylor import (
     chunked_num_den,
     init_taylor_state,
 )
-from repro.distributed import api as dist
 
 Array = jax.Array
 
@@ -127,7 +126,7 @@ def taylor_attention_context_parallel(
         return _ungroup(out).astype(v.dtype)
 
     spec = P(dp_axis, None, axis, None)
-    fn = dist.shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,
     )

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.models.ssm import _ssd_chunked
-from repro.distributed import api as dist
 
 Array = jax.Array
 
@@ -72,7 +71,7 @@ def ssd_context_parallel(
 
     spec4 = P(dp_axis, axis, None, None)
     spec3 = P(dp_axis, axis, None)
-    fn = dist.shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(spec4, spec3, spec4, spec4),
         out_specs=spec4,

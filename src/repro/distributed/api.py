@@ -14,8 +14,8 @@ import contextvars
 from typing import Mapping, Optional, Sequence, Tuple, Union
 
 import jax
-import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import get_abstract_mesh
 
 Array = jax.Array
 
@@ -63,9 +63,12 @@ def rules_for_mesh(mesh: Mesh, **overrides) -> Rules:
 
 @contextlib.contextmanager
 def sharding_rules(mesh: Mesh, rules: Optional[Rules] = None):
+    """Bind logical names to ``mesh`` axes and make ``mesh`` the current
+    mesh (``jax.set_mesh``) for every ``jit``/``shard_map`` traced inside."""
     token = _ACTIVE.set((mesh, rules if rules is not None else rules_for_mesh(mesh)))
     try:
-        yield
+        with jax.set_mesh(mesh):
+            yield
     finally:
         _ACTIVE.reset(token)
 
@@ -98,20 +101,9 @@ def constrain(x: Array, *axes: Optional[str]) -> Array:
     if len(axes) != x.ndim:
         return x
     # inside shard_map (Manual axes) constraints are meaningless/illegal
-    try:
-        from jax.sharding import AxisType, get_abstract_mesh
-
-        am = get_abstract_mesh()
-        if not am.empty and any(t == AxisType.Manual for t in am.axis_types):
-            return x
-    except ImportError:  # jax 0.4.x: shard_map binds mesh axes in the axis env
-        try:
-            from jax._src.core import get_axis_env
-
-            if get_axis_env().axis_sizes:
-                return x
-        except Exception:  # pragma: no cover - API drift
-            pass
+    am = get_abstract_mesh()
+    if not am.empty and AxisType.Manual in am.axis_types:
+        return x
     resolved = []
     for name, size in zip(axes, x.shape):
         if name == "*":  # dim left to the SPMD partitioner
@@ -147,19 +139,3 @@ def mesh_axis_size(mesh: Mesh, name: Union[str, Tuple[str, ...], None]) -> int:
         return out
     return mesh.shape[name]
 
-
-def shard_map(f, mesh: Mesh, in_specs, out_specs, check_vma: bool = False):
-    """Version-tolerant shard_map: jax ≥0.5 exposes ``jax.shard_map`` with a
-    ``check_vma`` kwarg; jax 0.4.x has ``jax.experimental.shard_map`` with
-    the same semantics under ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map  # noqa: PLC0415
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )

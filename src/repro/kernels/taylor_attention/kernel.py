@@ -14,10 +14,15 @@ index with VMEM-resident moment state):
     S1 += K_cᵀV_c ; z1 += ΣK ; s0 += ΣV ; z2 += KᵀK
     S2_t += ((K ⊗ K_t) reshaped)ᵀ V_c                  # D-tiled outer product
 
-VMEM budget (f32 state): S2 = D²·DVt·4B — with D=128, DVt=128 that is
-8.4 MiB, plus ≤3 MiB transients: fits a 16 MiB VMEM core.  D must be ≤128
-after padding (heads with d≤128 cover 9/10 assigned archs; d=256 heads —
-gemma-7b — stay on the XLA chunked path; see DESIGN.md §VMEM constraint).
+Query groups run in a ``fori_loop``, so VMEM use and compile time do not
+grow with G.  VMEM budget (f32 state): S2 = D²·DVt·4B — with D=128,
+DVt=128 that is 8.4 MiB.  With the q⊗q tiles, double-buffered blocks and
+the other moments, the v5e compiler reports a 21.4 MiB scoped allocation
+for the forward and 17.0 MiB for the backward pair (qwen2-1.5b widths,
+bf16): above Mosaic's 16 MiB default, so both set ``VMEM_LIMIT_BYTES``
+(64 MiB, which also covers G=48 MQA; a v5e core has 128 MiB).  D must be
+≤128 after padding (d=256 heads — gemma-7b — stay on the XLA chunked
+path; see DESIGN.md §VMEM constraint).
 
 Zero-padding contract (ops.py): padded key/value rows are all-zero, so every
 moment contribution vanishes and the causal mask alone keeps the constant-1
@@ -35,12 +40,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_CHUNK = 128
 D_TILE = 32  # first-axis tile of the second moment (controls transient size)
-
-# jax 0.4.x exposes the Mosaic compiler params as ``TPUCompilerParams``;
-# newer releases renamed it to ``CompilerParams``.  Take whichever exists.
-CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
+# Scoped-VMEM limit of the forward and backward kernels (module docstring).
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
 
 def scores(q, k, a, causal, order):
@@ -62,6 +63,19 @@ def dscores(dp, s, causal, a, order):
     """ds = causal(dp · d/ds[1 + s + s²/2]) · a — the VJP of ``scores``."""
     deriv = dp if order < 2 else dp * (1.0 + s)
     return jnp.where(causal, deriv, 0.0) * a
+
+
+def outer_tile(x, t0):
+    """Rows of the D-tiled outer product: ``[C, D] -> [C, D_TILE·D]`` with
+    ``out[c, i·D + j] = x[c, t0 + i] · x[c, j]``.
+
+    A static slice plus ``expand_dims``: fancy indexing (``x[:, a:b, None]``)
+    traces to a gather, which Mosaic cannot lower."""
+    c, d = x.shape
+    xt = jax.lax.slice_in_dim(x, t0, t0 + D_TILE, axis=1)
+    return (
+        jnp.expand_dims(xt, 2) * jnp.expand_dims(x, 1)
+    ).reshape(c, D_TILE * d)
 
 
 def accumulate_state(
@@ -94,9 +108,7 @@ def accumulate_state(
             k, k, (((0,), (0,)), ((), ())), preferred_element_type=f32
         )
         for t0 in range(0, d, D_TILE):
-            kk = (
-                k[:, t0 : t0 + D_TILE, None] * k[:, None, :]
-            ).reshape(C, D_TILE * d)  # [C, Dt*D]
+            kk = outer_tile(k, t0)  # [C, Dt*D]
             s2_ref[t0 * d : (t0 + D_TILE) * d, :] = s2_ref[
                 t0 * d : (t0 + D_TILE) * d, :
             ] + jax.lax.dot_general(
@@ -146,7 +158,7 @@ def _taylor_fwd_kernel(
 
     half_a2 = 0.5 * a * a
 
-    for g in range(G):
+    def group(g, carry):
         q = q_ref[0, g].astype(f32)  # [C, D]
         _, p = scores(q, k, a, causal, order)  # [C, C]
 
@@ -161,9 +173,7 @@ def _taylor_fwd_kernel(
             # quadratic numerator, D-tiled: (q ⊗ q_t) @ S2_t
             acc = jnp.zeros_like(num)
             for t0 in range(0, D, D_TILE):
-                qq = (
-                    q[:, t0 : t0 + D_TILE, None] * q[:, None, :]
-                ).reshape(C, D_TILE * D)  # [C, Dt*D]
+                qq = outer_tile(q, t0)  # [C, Dt*D]
                 acc = acc + jax.lax.dot(
                     qq, s2_ref[t0 * D : (t0 + D_TILE) * D, :],
                     preferred_element_type=f32,
@@ -174,6 +184,9 @@ def _taylor_fwd_kernel(
 
         den = jnp.where(jnp.abs(den) < 1e-6, 1e-6, den)
         out_ref[0, g] = (num / den[:, None]).astype(out_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, G, group, 0)
 
     # ---- state update with this chunk's keys/values ----
     accumulate_state(
@@ -242,8 +255,9 @@ def taylor_fwd_pallas(
             pltpu.VMEM((d, d), jnp.float32),
             pltpu.VMEM((d * d, dv_tile), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
     )(q, k, v)

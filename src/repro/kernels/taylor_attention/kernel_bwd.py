@@ -25,9 +25,11 @@ rows come out exactly zero (they are sliced off anyway).  Padded D columns
 contribute 0 to every dot product.
 
 VMEM budget mirrors the forward: the D-tiled second moment (or its
-gradient) dominates at D²·DVt·4B = 8.4 MiB for D = DVt = 128, plus ≤4 MiB
-transients — one 16 MiB core per program.  D ≤ 128 and DV ≤ 128 after
-padding; larger heads stay on the XLA taylor_vjp path (ops.py dispatch).
+gradient) dominates at D²·DVt·4B = 8.4 MiB for D = DVt = 128; the pair
+runs under the forward's ``VMEM_LIMIT_BYTES`` (kernel.py docstring).
+Query groups run in a ``fori_loop``, as in the forward.  D ≤ 128 and
+DV ≤ 128 after padding; larger heads stay on the XLA taylor_vjp path
+(ops.py dispatch).
 """
 
 from __future__ import annotations
@@ -43,9 +45,10 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.taylor_attention.kernel import (
     D_TILE,
     DEFAULT_CHUNK,
-    CompilerParams,
+    VMEM_LIMIT_BYTES,
     accumulate_state,
     dscores,
+    outer_tile,
     scores,
 )
 
@@ -101,7 +104,7 @@ def _taylor_bwd_dq_kernel(
     count = (c_idx * C).astype(f32)
     half_a2 = 0.5 * a * a
 
-    for g in range(G):
+    def group(g, carry):
         q = q_ref[0, g].astype(f32)  # [C, D]
         do = do_ref[0, g].astype(f32)  # [C, DV]
         o = o_ref[0, g].astype(f32)  # [C, DV]
@@ -149,6 +152,9 @@ def _taylor_bwd_dq_kernel(
         dq_ref[0, g] = dq.astype(dq_ref.dtype)
         den_ref[0, g] = den
         dden_ref[0, g] = dden
+        return carry
+
+    jax.lax.fori_loop(0, G, group, 0)
 
     accumulate_state(
         k, v, None, s1_ref, z1_ref, z2_ref, s2_ref, order=order, d=D
@@ -221,13 +227,12 @@ def _taylor_bwd_dkv_kernel(
             w3 = w.reshape(C, D_TILE, D)
             parts.append(2.0 * jnp.sum(w3 * k[:, None, :], axis=2))  # [C, Dt]
             # dv[j, v] += Σ_{t,e} k[j,t]·k[j,e]·dS2[t,e,v]
-            kk = (
-                k[:, t0 : t0 + D_TILE, None] * k[:, None, :]
-            ).reshape(C, D_TILE * D)
+            kk = outer_tile(k, t0)
             dv = dv + jax.lax.dot(kk, block, preferred_element_type=f32)
         dk = dk + jnp.concatenate(parts, axis=1)
 
-    for g in range(G):
+    def group(g, carry):
+        dk, dv = carry
         q = q_ref[0, g].astype(f32)  # [C, D]
         do = do_ref[0, g].astype(f32)  # [C, DV]
         den = den_ref[0, g]  # [C] (already clamped by the dq kernel)
@@ -260,15 +265,16 @@ def _taylor_bwd_dkv_kernel(
                 preferred_element_type=f32,
             )
             for t0 in range(0, D, D_TILE):
-                qq = (
-                    q[:, t0 : t0 + D_TILE, None] * q[:, None, :]
-                ).reshape(C, D_TILE * D)
+                qq = outer_tile(q, t0)
                 ds2_ref[t0 * D : (t0 + D_TILE) * D, :] = ds2_ref[
                     t0 * D : (t0 + D_TILE) * D, :
                 ] + half_a2 * jax.lax.dot_general(
                     qq, dnum, (((0,), (0,)), ((), ())),
                     preferred_element_type=f32,
                 )
+        return dk, dv
+
+    dk, dv = jax.lax.fori_loop(0, G, group, (dk, dv))
 
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
@@ -347,8 +353,9 @@ def taylor_bwd_pallas(
             jax.ShapeDtypeStruct((bk, g, n), jnp.float32),
         ],
         scratch_shapes=moment_scratch,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
     )(q, k, v, dout, out)
@@ -375,8 +382,9 @@ def taylor_bwd_pallas(
             jax.ShapeDtypeStruct((bk, n, dv), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((1, dv), jnp.float32)] + moment_scratch,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
     )(q, k, v, dout, den, dden)

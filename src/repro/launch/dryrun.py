@@ -211,16 +211,15 @@ def lower_cell(arch: str, shape: str, mesh, backend=None, donate=True, save_hlo=
         raise ValueError(spec.kind)
 
     t0 = time.monotonic()
-    with mesh:
-        with dist.sharding_rules(mesh, rules):
-            lowered = fn.lower(*args)
-            # trip-exact global flops/bytes (jaxpr walker; see analysis/flops)
-            if spec.kind == "train":
-                walker = count_fn(step, *args)
-            elif spec.kind == "prefill":
-                walker = count_fn(fwd, *args)
-            else:
-                walker = count_fn(step_fn, *args)
+    with dist.sharding_rules(mesh, rules):
+        lowered = fn.lower(*args)
+        # trip-exact global flops/bytes (jaxpr walker; see analysis/flops)
+        if spec.kind == "train":
+            walker = count_fn(step, *args)
+        elif spec.kind == "prefill":
+            walker = count_fn(fwd, *args)
+        else:
+            walker = count_fn(step_fn, *args)
     t_lower = time.monotonic() - t0
     t0 = time.monotonic()
     compiled = lowered.compile()

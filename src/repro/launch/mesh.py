@@ -8,7 +8,14 @@ device query, and tests must keep seeing 1 CPU device.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def _auto_mesh(shape, axes) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``: the partitioner places
+    what the ``NamedSharding``s and ``constrain`` annotations leave open
+    (``make_mesh`` otherwise defaults to ``Explicit`` axes)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -17,7 +24,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     axis (data-parallel across pods over DCN/ICI)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> Mesh:
@@ -25,7 +32,7 @@ def make_host_mesh(data: int = 1, model: int = 1) -> Mesh:
     n = len(jax.devices())
     if data * model > n:
         data, model = n, 1
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def make_serve_mesh(slots: int = 1, model: int = 1) -> Mesh:
@@ -50,4 +57,4 @@ def make_serve_mesh(slots: int = 1, model: int = 1) -> Mesh:
             f"make_serve_mesh({slots}×{model}) needs {slots * model} "
             f"devices but only {n} are visible"
         )
-    return jax.make_mesh((slots, model), ("data", "model"))
+    return _auto_mesh((slots, model), ("data", "model"))

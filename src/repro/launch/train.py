@@ -13,12 +13,12 @@ committed checkpoint (exactly — the data pipeline is stateless in step).
 from __future__ import annotations
 
 import argparse
-import functools
 import time
+from typing import Callable, Dict, List, NamedTuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+from jax.sharding import Mesh
 
 from repro.configs import ARCHS, get_config, get_reduced
 from repro.data import make_task
@@ -29,9 +29,10 @@ from repro.distributed.sharding import (
     opt_state_specs,
     param_specs,
 )
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import lm_init
-from repro.models.config import count_params
+from repro.models.config import ModelConfig, count_params
 from repro.optim import adafactor, adamw, cosine_warmup, sgdm
 from repro.train import TrainLoopConfig, TrainState, make_train_step, run_training
 
@@ -68,24 +69,36 @@ def make_sharded_state_and_step(cfg, optimizer, mesh, rules, batch_shapes, seed=
             opt_state=optimizer.init(params),
         )
 
-    with mesh:
-        with dist.sharding_rules(mesh, rules):
-            state = jax.jit(init_fn, out_shardings=state_ns)(
-                jax.random.PRNGKey(seed)
-            )
-            step = make_train_step(cfg, optimizer)
-            metrics_ns = {k: NamedSharding(mesh, P()) for k in
-                          ("loss", "aux_loss", "total_loss")}
-            step_fn = jax.jit(
-                step,
-                in_shardings=(state_ns, batch_ns),
-                out_shardings=(state_ns, metrics_ns),
-                donate_argnums=(0,),
-            )
+    with dist.sharding_rules(mesh, rules):
+        state = jax.jit(init_fn, out_shardings=state_ns)(
+            jax.random.PRNGKey(seed)
+        )
+        step = make_train_step(cfg, optimizer)
+        metrics_ns = {k: NamedSharding(mesh, P()) for k in
+                      ("loss", "aux_loss", "total_loss")}
+        step_fn = jax.jit(
+            step,
+            in_shardings=(state_ns, batch_ns),
+            out_shardings=(state_ns, metrics_ns),
+            donate_argnums=(0,),
+        )
     return state, step_fn, state_ns, batch_ns
 
 
-def main(argv=None):
+class TrainRun(NamedTuple):
+    """What ``main`` leaves behind: the final state, the loss of every step
+    this run took, and what it ran them with (the jitted sharded step, the
+    mesh and rules it is traced under, and the data)."""
+
+    state: TrainState
+    losses: List[float]
+    step_fn: Callable
+    mesh: Mesh
+    rules: dist.Rules
+    batch_at: Callable[[int], Dict[str, jax.Array]]
+
+
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCHS, required=True)
     ap.add_argument("--reduced", action="store_true",
@@ -108,16 +121,22 @@ def main(argv=None):
     ap.add_argument("--production-mesh", action="store_true")
     ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--max-wall-seconds", type=float, default=None)
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
-    if args.reduced:
-        cfg = get_reduced(args.arch)
-    else:
-        cfg = get_config(args.arch)
+
+def config_from_args(args: argparse.Namespace) -> ModelConfig:
+    cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     if args.backend and not cfg.is_attention_free:
         cfg = cfg.replace(attention=args.backend)
     if args.seq % cfg.attn_chunk != 0:
         cfg = cfg.replace(attn_chunk=min(args.seq, cfg.attn_chunk))
+    return cfg
+
+
+def main(argv=None) -> TrainRun:
+    args = parse_args(argv)
+    print(f"[train] compile cache: {enable_compile_cache()}")
+    cfg = config_from_args(args)
 
     if args.production_mesh:
         mesh = make_production_mesh(multi_pod=args.multi_pod)
@@ -147,10 +166,13 @@ def main(argv=None):
         b.update(task.extras_at(step, cfg))
         return {k: jnp.asarray(v) for k, v in b.items()}
 
+    losses = []  # device scalars: reading them here would sync every step
+
     def wrapped_step(state, batch):
-        with mesh:
-            with dist.sharding_rules(mesh, rules):
-                return step_fn(state, batch)
+        with dist.sharding_rules(mesh, rules):
+            state, metrics = step_fn(state, batch)
+        losses.append(metrics["loss"])
+        return state, metrics
 
     loop = TrainLoopConfig(
         total_steps=args.steps,
@@ -164,7 +186,10 @@ def main(argv=None):
     dt = time.monotonic() - t0
     final = int(jax.device_get(state.step))
     print(f"[train] done: step={final} wall={dt:.1f}s")
-    return state
+    return TrainRun(
+        state=state, losses=[float(x) for x in losses], step_fn=step_fn,
+        mesh=mesh, rules=rules, batch_at=batch_at,
+    )
 
 
 if __name__ == "__main__":

@@ -276,7 +276,7 @@ def _moe_ep_a2a(params, x: Array, cfg: ModelConfig, mesh, rules) -> Tuple[Array,
         jax.tree_util.tree_map(lambda _: P(ep, fsdp if fsdp else None), experts),
     )
     out_specs = (P(dp, None, None), P())
-    fn = dist.shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )
     y, aux = fn(x, router_w, experts)

@@ -568,9 +568,8 @@ class ServeEngine:
         else:
             from repro.distributed import api as dist  # noqa: PLC0415
 
-            with self.mesh:
-                with dist.sharding_rules(self.mesh, self.rules):
-                    yield
+            with dist.sharding_rules(self.mesh, self.rules):
+                yield
 
     def _decode_scan_fn(self, steps: int, sampling: bool, max_top_k: int):
         """Per-engine compiled decode_scan variants (the sharded builds pin

@@ -53,12 +53,10 @@ def run_training(
         log(f"[loop] resumed from checkpoint step {start_step}")
 
     t0 = time.monotonic()
-    losses = []
     for step in range(start_step, loop.total_steps):
         state, metrics = step_fn(state, batch_at(step))
         if loop.log_every and (step + 1) % loop.log_every == 0:
             m = {k: float(jax.device_get(v)) for k, v in metrics.items()}
-            losses.append(m.get("loss", 0.0))
             log(f"[loop] step {step + 1}/{loop.total_steps} " +
                 " ".join(f"{k}={v:.4f}" for k, v in sorted(m.items())))
         if (

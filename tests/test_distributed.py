@@ -116,9 +116,8 @@ def test_sharded_training_matches_single_device():
         losses_sh = []
         for s in range(3):
             batch = {k: jnp.asarray(v) for k, v in task.batch_at(s).items()}
-            with mesh:
-                with dist.sharding_rules(mesh, rules):
-                    state2, m = step_fn(state2, batch)
+            with dist.sharding_rules(mesh, rules):
+                state2, m = step_fn(state2, batch)
             losses_sh.append(float(m["loss"]))
         print(json.dumps({"ref": losses_ref, "sh": losses_sh}))
     """)
@@ -192,9 +191,8 @@ def test_cp_attention_training_matches_tp():
             ls = []
             for s in range(2):
                 batch = {k: jnp.asarray(v) for k, v in task.batch_at(s).items()}
-                with mesh:
-                    with dist.sharding_rules(mesh, rules):
-                        state, m = step_fn(state, batch)
+                with dist.sharding_rules(mesh, rules):
+                    state, m = step_fn(state, batch)
                 ls.append(float(m["loss"]))
             losses[mode] = ls
         print(json.dumps(losses))
@@ -212,9 +210,9 @@ def test_context_parallel_state_exchange():
         import jax, jax.numpy as jnp, numpy as np
         from repro.core import TaylorConfig, taylor_attention_chunked
         from repro.core.context_parallel import taylor_attention_context_parallel
-        from jax.sharding import PartitionSpec as P
+        from jax.sharding import AxisType, PartitionSpec as P
 
-        mesh = jax.make_mesh((8,), ("seq",))
+        mesh = jax.make_mesh((8,), ("seq",), axis_types=(AxisType.Auto,))
         rng = np.random.default_rng(0)
         b, h, hk, n, d, dv = 1, 2, 1, 512, 16, 16
         q = jnp.asarray(rng.normal(size=(b, h, n, d)), jnp.float32)
@@ -236,8 +234,9 @@ def test_ssd_context_parallel_exact():
         import jax, jax.numpy as jnp, numpy as np
         from repro.models.ssm import _ssd_chunked
         from repro.core.ssd_context_parallel import ssd_context_parallel
+        from jax.sharding import AxisType
 
-        mesh = jax.make_mesh((8,), ("seq",))
+        mesh = jax.make_mesh((8,), ("seq",), axis_types=(AxisType.Auto,))
         rng = np.random.default_rng(0)
         b, n, H, Pd, G, N = 2, 512, 4, 16, 1, 8
         x = jnp.asarray(rng.normal(size=(b, n, H, Pd)), jnp.float32)
@@ -256,3 +255,41 @@ def test_ssd_context_parallel_exact():
         print("SSD_CP_OK")
     """)
     assert "SSD_CP_OK" in out
+
+
+def test_pallas_training_sharded_matches_single_device():
+    """The Pallas kernel pair runs once per shard (``shard_map``) on a
+    mesh: data- and tensor-parallel training losses match one device."""
+    out = _run_subprocess("""
+        import jax, jax.numpy as jnp, json
+        from repro.configs import get_reduced
+        from repro.data import make_task
+        from repro.optim import adamw, constant
+        from repro.launch.train import make_sharded_state_and_step
+        from repro.distributed import api as dist
+        from repro.launch.mesh import make_host_mesh
+
+        cfg = get_reduced("smollm-135m").replace(attn_impl="pallas")
+        task = make_task("bigram", cfg.vocab, 32, 8, seed=3)
+        shapes = {k: jax.ShapeDtypeStruct(v.shape, v.dtype)
+                  for k, v in task.batch_at(0).items()}
+        losses = {}
+        for shape in ((1, 1), (4, 2), (8, 1)):
+            mesh = make_host_mesh(*shape)
+            rules = dist.rules_for_mesh(mesh)
+            state, step_fn, _, _ = make_sharded_state_and_step(
+                cfg, adamw(constant(1e-3)), mesh, rules, shapes, seed=0)
+            ls = []
+            for s in range(2):
+                batch = {k: jnp.asarray(v) for k, v in task.batch_at(s).items()}
+                with dist.sharding_rules(mesh, rules):
+                    state, m = step_fn(state, batch)
+                ls.append(float(m["loss"]))
+            losses[str(shape)] = ls
+        print(json.dumps(losses))
+    """)
+    data = json.loads(out.strip().splitlines()[-1])
+    ref = data.pop("(1, 1)")
+    for shape, ls in data.items():
+        for a, b in zip(ref, ls):
+            assert abs(a - b) < 2e-3, (shape, data, ref)

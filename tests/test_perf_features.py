@@ -73,7 +73,9 @@ def test_int8_a2a_moe_close_to_exact():
         from repro.models import moe as moe_mod
         from repro.distributed import api as dist
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_host_mesh
+
+        mesh = make_host_mesh(2, 4)
         rules = dist.rules_for_mesh(mesh)
         base = MoEConfig(n_experts=8, top_k=2, d_ff_expert=32,
                          capacity_factor=8.0, impl="ep_a2a")
@@ -83,12 +85,11 @@ def test_int8_a2a_moe_close_to_exact():
                         jnp.float32)
         import dataclasses
         cfg8 = cfg.replace(moe=dataclasses.replace(base, a2a_quant="int8"))
-        with mesh:
-            with dist.sharding_rules(mesh, rules):
-                y, _ = jax.jit(lambda p, x: moe_mod.moe_apply(p, x, cfg))(params, x)
-                y8, _ = jax.jit(lambda p, x: moe_mod.moe_apply(p, x, cfg8))(params, x)
-                g = jax.jit(jax.grad(lambda p: jnp.sum(
-                    moe_mod.moe_apply(p, x, cfg8)[0] ** 2)))(params)
+        with dist.sharding_rules(mesh, rules):
+            y, _ = jax.jit(lambda p, x: moe_mod.moe_apply(p, x, cfg))(params, x)
+            y8, _ = jax.jit(lambda p, x: moe_mod.moe_apply(p, x, cfg8))(params, x)
+            g = jax.jit(jax.grad(lambda p: jnp.sum(
+                moe_mod.moe_apply(p, x, cfg8)[0] ** 2)))(params)
         rel = float(jnp.max(jnp.abs(y - y8)) / (jnp.max(jnp.abs(y)) + 1e-9))
         gn = sum(float(jnp.sum(jnp.abs(v))) for v in jax.tree_util.tree_leaves(g))
         assert rel < 0.05, rel      # int8 quantization error bound

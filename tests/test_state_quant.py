@@ -37,13 +37,13 @@ STEPS = 32
 PROMPT = 12
 N_MAX = STEPS + PROMPT + 4
 
-# measured maxima over the full grid (see module docstring): MAE 0.103 /
-# 0.603, flip margins 0.089 / 0.680 for int8 / fp8.
+# measured maxima over the full grid (see module docstring): MAE 0.086 /
+# 0.386, flip margins 0.034 / 0.602 for int8 / fp8 (jax 0.9.0, CPU).
 MAE_TOL = {"int8": 0.25, "fp8": 1.25}
 MARGIN = {"int8": 0.2, "fp8": 1.5}
 
 # free-running identity horizons, pinned on the cell named below
-# (measured first mismatch at steps 39 / 43).
+# (measured: no mismatch within the 32 steps for either dtype).
 HORIZON = {"int8": 32, "fp8": 24}
 HORIZON_CELL = {"int8": ("qwen2-1.5b", 2, 1), "fp8": ("qwen2-1.5b", 1, 1)}
 
