@@ -14,6 +14,11 @@ leaves the accelerator):
     chunk index) carrying the accumulated future state-gradients
     (dS0/dS1/dz1/dz2/dS2) in VMEM scratch, and emits dk, dv.
 
+The two calls are named ``taylor_bwd_dq`` and ``taylor_bwd_dkv``: the
+compiled program, and so a profiler trace, shows each under its own name
+rather than the enclosing remat's (``checkpoint``).  Neither contains the
+forward's ``taylor_attention_kernel``.
+
 Compute: ≈2× the forward (the standard recompute trade — see
 DESIGN.md §Backward).  Residual HBM: q, k, v, dout plus the [*, G, N]
 den/dden rows; no per-chunk state is ever materialised off-chip.
@@ -358,6 +363,7 @@ def taylor_bwd_pallas(
             vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
+        name="taylor_bwd_dq",
     )(q, k, v, dout, out)
 
     # ---- pass 2 (reverse direction): dk, dv ----
@@ -387,6 +393,7 @@ def taylor_bwd_pallas(
             vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
+        name="taylor_bwd_dkv",
     )(q, k, v, dout, den, dden)
 
     return dq, dk, dvv

@@ -74,6 +74,11 @@ from repro.serve.engine import (
 
 Array = jax.Array
 
+# Engine phases ``ServeEngine.timings()`` reports, each also a
+# ``serve.<phase>`` span in a profiler trace (docs/serving.md
+# §Observability).
+PHASES = ("step", "admit", "prefill", "prefill_chunk", "decode", "health")
+
 
 class Status(enum.Enum):
     """Terminal outcome of one request (the status lattice).
@@ -116,6 +121,10 @@ class RequestResult:
       first_token_at: engine-clock time the first output token existed
         (end of prefill); None if the request never reached a slot.
       finished_at: engine-clock time the terminal status was recorded.
+      admitted_at: engine-clock time the request first got a slot (or its
+        chunked admission began); None if it never did.  With
+        ``submitted_at`` it gives the queue wait, with ``first_token_at``
+        the admission (prefill) time.
     """
 
     status: Status
@@ -126,6 +135,7 @@ class RequestResult:
     submitted_at: Optional[float] = None
     first_token_at: Optional[float] = None
     finished_at: Optional[float] = None
+    admitted_at: Optional[float] = None
 
 
 class RequestRejected(ValueError):
@@ -353,6 +363,7 @@ class _Tracked:
     saved_state: Any = None
     saved_token: int = 0
     saved_pos: int = 0
+    admitted_at: Optional[float] = None
 
     def effective_tokens(self) -> np.ndarray:
         toks = np.asarray(self.req.tokens).reshape(-1).astype(np.int32)
@@ -548,6 +559,7 @@ class ServeEngine:
         self._slots = [_Slot() for _ in range(max_slots)]
         self._block = 0                   # decode-block counter (1-based)
         self._stats: Counter = Counter()
+        self._timings: Counter = Counter()  # host ns per phase (_phase)
         # Per-slot device-facing vectors (host copies are authoritative).
         self._token = np.zeros((max_slots,), np.int32)
         self._pos = np.zeros((max_slots,), np.int32)
@@ -555,6 +567,25 @@ class ServeEngine:
         self._topk = np.zeros((max_slots,), np.int32)
         self._eos = np.full((max_slots,), -1, np.int32)
         self._spec = spec_mod.Speculator(self)
+
+    # -- observability ------------------------------------------------------
+
+    @contextlib.contextmanager
+    def _phase(self, name: str, **args):
+        """Times one engine phase and marks it in a profiler trace.
+
+        Opens the ``serve.<name>`` trace annotation (``args`` become its
+        arguments) and adds the phase's host time, by
+        ``time.perf_counter_ns`` and not the injected ``clock`` (virtual
+        under the load harness), and a count of one to ``timings()``.
+        Always on; kept out of ``stats()``, which replays compare."""
+        t = time.perf_counter_ns()
+        try:
+            with jax.profiler.TraceAnnotation(f"serve.{name}", **args):
+                yield
+        finally:
+            self._timings[f"{name}_ns"] += time.perf_counter_ns() - t
+            self._timings[f"{name}_n"] += 1
 
     # -- mesh helpers -------------------------------------------------------
 
@@ -785,6 +816,7 @@ class ServeEngine:
             submitted_at=tr.submitted_at if tr is not None else None,
             first_token_at=tr.first_token_at if tr is not None else None,
             finished_at=self._clock(),
+            admitted_at=tr.admitted_at if tr is not None else None,
         )
         self._stats[status.value] += 1
 
@@ -949,30 +981,31 @@ class ServeEngine:
         p = self._partial
         tr = self._requests[p.rid]
         req = tr.req
-        toks = tr.effective_tokens()
-        n = int(toks.shape[-1])
-        take = min(self._chunk_for(tr), n - p.consumed)
-        chunk = jnp.asarray(toks[None, p.consumed : p.consumed + take],
-                            jnp.int32)
-        with self._device_ctx():
-            p.logits, p.caches = self._prefill_chunk_fn()(
-                self.params, chunk, p.caches,
-                jnp.asarray(p.consumed, jnp.int32),
-            )
-        self._stats["dispatches"] += 1
-        self._stats["prefill_dispatches"] += 1
-        self._stats["prefill_tokens"] += take
-        p.consumed += take
-        p.last_chunk_block = self._block
-        if p.consumed < n:
-            return
-        self._rng, sub = jax.random.split(self._rng)
-        first = int(np.asarray(sample_tokens(
-            p.logits, sub,
-            jnp.asarray([req.temperature], jnp.float32),
-            jnp.asarray([req.top_k], jnp.int32),
-            max_top_k=req.top_k,
-        ))[0])
+        with self._phase("prefill_chunk"):
+            toks = tr.effective_tokens()
+            n = int(toks.shape[-1])
+            take = min(self._chunk_for(tr), n - p.consumed)
+            chunk = jnp.asarray(toks[None, p.consumed : p.consumed + take],
+                                jnp.int32)
+            with self._device_ctx():
+                p.logits, p.caches = self._prefill_chunk_fn()(
+                    self.params, chunk, p.caches,
+                    jnp.asarray(p.consumed, jnp.int32),
+                )
+            self._stats["dispatches"] += 1
+            self._stats["prefill_dispatches"] += 1
+            self._stats["prefill_tokens"] += take
+            p.consumed += take
+            p.last_chunk_block = self._block
+            if p.consumed < n:
+                return
+            self._rng, sub = jax.random.split(self._rng)
+            first = int(np.asarray(sample_tokens(
+                p.logits, sub,
+                jnp.asarray([req.temperature], jnp.float32),
+                jnp.asarray([req.top_k], jnp.int32),
+                max_top_k=req.top_k,
+            ))[0])
         self._install(p.slot, p.rid, tr, p.caches, first, n)
         self._partial = None
 
@@ -1122,6 +1155,8 @@ class ServeEngine:
                 st = self._slots[slot]
                 st.rid, st.prefilling, st.done = rid, True, False
                 st.remaining, st.out = 0, []
+                if tr.admitted_at is None:
+                    tr.admitted_at = self._clock()
                 with self._device_ctx():
                     partial_caches = slots_mod.init_slot_caches(
                         self.cfg, 1, self.n_max, self._cache_dtype,
@@ -1155,29 +1190,34 @@ class ServeEngine:
             for g in group:
                 self._queue.remove(g)
             trs = [self._requests[g] for g in group]
-            batch = {"tokens": jnp.asarray(
-                np.stack([t.effective_tokens() for t in trs]), jnp.int32
-            )}
-            for k in trs[0].req.extras:
-                batch[k] = jnp.asarray(
-                    np.concatenate([np.asarray(t.req.extras[k])
-                                    for t in trs])
-                )
-            with self._device_ctx():
-                logits, pref_caches = _jitted_prefill(self.cfg, self.n_max)(
-                    self.params, batch
-                )
-            self._stats["dispatches"] += 1
-            self._stats["prefill_dispatches"] += 1
-            self._stats["prefill_tokens"] += int(glen) * len(group)
-            self._rng, sub = jax.random.split(self._rng)
-            temps = jnp.asarray([t.req.temperature for t in trs],
-                                jnp.float32)
-            topks = jnp.asarray([t.req.top_k for t in trs], jnp.int32)
-            firsts = np.asarray(sample_tokens(
-                logits, sub, temps, topks,
-                max_top_k=max(t.req.top_k for t in trs),
-            ))
+            now = self._clock()
+            for t in trs:
+                if t.admitted_at is None:
+                    t.admitted_at = now
+            with self._phase("prefill", group=len(group), length=int(glen)):
+                batch = {"tokens": jnp.asarray(
+                    np.stack([t.effective_tokens() for t in trs]), jnp.int32
+                )}
+                for k in trs[0].req.extras:
+                    batch[k] = jnp.asarray(
+                        np.concatenate([np.asarray(t.req.extras[k])
+                                        for t in trs])
+                    )
+                with self._device_ctx():
+                    logits, pref_caches = _jitted_prefill(
+                        self.cfg, self.n_max
+                    )(self.params, batch)
+                self._stats["dispatches"] += 1
+                self._stats["prefill_dispatches"] += 1
+                self._stats["prefill_tokens"] += int(glen) * len(group)
+                self._rng, sub = jax.random.split(self._rng)
+                temps = jnp.asarray([t.req.temperature for t in trs],
+                                    jnp.float32)
+                topks = jnp.asarray([t.req.top_k for t in trs], jnp.int32)
+                firsts = np.asarray(sample_tokens(
+                    logits, sub, temps, topks,
+                    max_top_k=max(t.req.top_k for t in trs),
+                ))
             for j, (g, t) in enumerate(zip(group, trs)):
                 slot = free.pop(0)
                 with self._device_ctx():
@@ -1294,7 +1334,7 @@ class ServeEngine:
         occupied = any(s.rid is not None for s in self._slots)
         if not occupied:
             return
-        with self._device_ctx():
+        with self._phase("health"), self._device_ctx():
             health = np.asarray(self.state_store.health(self.caches))
         self._stats["health_checks"] += 1
         if health.all():
@@ -1343,7 +1383,12 @@ class ServeEngine:
         dispatch (with bounded retry / cache rebuild) → corruption
         injection → health sweep → retire.  Exposed for tests and for
         callers interleaving submission with decoding; ``run`` loops it.
+        Timed as the ``step`` phase (``timings()``).
         """
+        with self._phase("step"):
+            return self._step()
+
+    def _step(self) -> bool:
         self._block += 1
         now = self._clock()
         if self.fault_plan is not None:
@@ -1357,7 +1402,8 @@ class ServeEngine:
         self._retire_finished()
         self._release_retries()
         self._preempt()
-        self._admit()
+        with self._phase("admit"):
+            self._admit()
         # Speculative rounds run BEFORE the decode block: due greedy slots
         # draft + verify (one chunked dispatch per depth) and are excluded
         # from this block's active mask — the decode scan preserves
@@ -1376,56 +1422,59 @@ class ServeEngine:
                 self._health_sweep()
             self._retire_finished()
             return self._has_work()
-        steps = min(
-            self.decode_block,
-            max(s.remaining for s in self._slots
-                if s.rid is not None and not s.done and not s.prefilling),
-        )
-        # steps and max_top_k are static jit keys: bucket both to powers of
-        # two so the number of compiled full-model scan variants stays
-        # O(log) in the values clients supply, not O(distinct values).
-        # Over-decoding a few tokens past the smallest budget is harmless —
-        # the host trims and retired slots freeze.
-        steps = min(self.decode_block, _next_pow2(max(steps, 1)))
-        # Static specialization for the compiled scan: all-greedy batches
-        # (the common case) skip sampling entirely, and top-k is bounded
-        # by the largest k among occupied slots.
-        occupied = [i for i, s in enumerate(self._slots)
-                    if s.rid is not None and not s.prefilling]
-        sampling = any(self._temp[i] > 0 for i in occupied)
-        max_top_k = int(max((self._topk[i] for i in occupied), default=0))
-        max_top_k = _next_pow2(max_top_k) if max_top_k > 0 else 0
-        self._rng, sub = jax.random.split(self._rng)
-        if self.state_store.paged:
-            # Every active slot writes up to ``steps`` new KV rows this
-            # dispatch — grow its page prefix first (host-side table,
-            # pushed once if anything changed).
-            for i in np.flatnonzero(active):
-                self.caches = self.state_store.ensure_tokens(
-                    self.caches, int(i), int(self._pos[i]) + int(steps)
-                )
-        scan_fn = self._decode_scan_fn(int(steps), bool(sampling), max_top_k)
-        try:
-            (self.caches, token, pos, dev_active, _, toks, mask) = (
-                self._dispatch(scan_fn, (
-                    self.params,
-                    self.caches,
-                    jnp.asarray(self._token),
-                    jnp.asarray(self._pos),
-                    jnp.asarray(active),
-                    jnp.asarray(self._temp),
-                    jnp.asarray(self._topk),
-                    jnp.asarray(self._eos),
-                    sub,
-                ))
+        with self._phase("decode"):
+            steps = min(
+                self.decode_block,
+                max(s.remaining for s in self._slots
+                    if s.rid is not None and not s.done and not s.prefilling),
             )
-        except Exception as e:  # noqa: BLE001 — resilience boundary
-            self._rebuild_after_loss(f"decode dispatch failed: {e}")
-            return self._has_work()
-        self._stats["dispatches"] += 1
-        self._stats["decode_dispatches"] += 1
-        toks = np.asarray(toks)
-        mask = np.asarray(mask)
+            # steps and max_top_k are static jit keys: bucket both to powers
+            # of two so the number of compiled full-model scan variants
+            # stays O(log) in the values clients supply, not O(distinct
+            # values).  Over-decoding a few tokens past the smallest budget
+            # is harmless — the host trims and retired slots freeze.
+            steps = min(self.decode_block, _next_pow2(max(steps, 1)))
+            # Static specialization for the compiled scan: all-greedy
+            # batches (the common case) skip sampling entirely, and top-k is
+            # bounded by the largest k among occupied slots.
+            occupied = [i for i, s in enumerate(self._slots)
+                        if s.rid is not None and not s.prefilling]
+            sampling = any(self._temp[i] > 0 for i in occupied)
+            max_top_k = int(max((self._topk[i] for i in occupied),
+                                default=0))
+            max_top_k = _next_pow2(max_top_k) if max_top_k > 0 else 0
+            self._rng, sub = jax.random.split(self._rng)
+            if self.state_store.paged:
+                # Every active slot writes up to ``steps`` new KV rows this
+                # dispatch — grow its page prefix first (host-side table,
+                # pushed once if anything changed).
+                for i in np.flatnonzero(active):
+                    self.caches = self.state_store.ensure_tokens(
+                        self.caches, int(i), int(self._pos[i]) + int(steps)
+                    )
+            scan_fn = self._decode_scan_fn(int(steps), bool(sampling),
+                                           max_top_k)
+            try:
+                (self.caches, token, pos, dev_active, _, toks, mask) = (
+                    self._dispatch(scan_fn, (
+                        self.params,
+                        self.caches,
+                        jnp.asarray(self._token),
+                        jnp.asarray(self._pos),
+                        jnp.asarray(active),
+                        jnp.asarray(self._temp),
+                        jnp.asarray(self._topk),
+                        jnp.asarray(self._eos),
+                        sub,
+                    ))
+                )
+            except Exception as e:  # noqa: BLE001 — resilience boundary
+                self._rebuild_after_loss(f"decode dispatch failed: {e}")
+                return self._has_work()
+            self._stats["dispatches"] += 1
+            self._stats["decode_dispatches"] += 1
+            toks = np.asarray(toks)
+            mask = np.asarray(mask)
         # np.array (copy): np.asarray of a jax array is a read-only view,
         # and _admit writes these in place.
         self._token = np.array(token, np.int32)
@@ -1533,6 +1582,29 @@ class ServeEngine:
             1 for s in self._slots if s.rid is not None
         )
         return out
+
+    def timings(self) -> Dict[str, int]:
+        """Host time and count of each engine phase since construction.
+
+        For every phase of ``PHASES``: ``<phase>_ns``, its summed host time
+        by ``time.perf_counter_ns`` (wall clock even under an injected
+        virtual ``clock``), and ``<phase>_n``, how often it ran.  Phases,
+        nested as listed, each also a ``serve.<phase>`` trace annotation:
+        ``step`` (all of ``step()``) holds ``admit`` (admission, which
+        holds ``prefill``, one batched whole-prompt prefill up to its first
+        tokens on the host, and ``prefill_chunk``, one chunk of a chunked
+        admission), ``decode`` (one decode block, from building its
+        arguments to its tokens on the host) and ``health`` (one
+        ``state_health`` sweep).  Kept apart from ``stats()``, whose
+        counters replays compare for equality (docs/serving.md
+        §Observability).
+
+        Returns:
+          Dict of ``<phase>_ns`` / ``<phase>_n`` to int (0 for a phase that
+          never ran).
+        """
+        return {f"{p}_{u}": self._timings[f"{p}_{u}"]
+                for p in PHASES for u in ("ns", "n")}
 
     @property
     def slot_state_bytes(self) -> int:

@@ -135,7 +135,7 @@ def test_engine_classes_documented():
                 VirtualClock, CostModel, SLO, LoadReport, DraftProposer,
                 NgramProposer, Order1SelfDraft, Speculator):
         assert (inspect.getdoc(cls) or "").strip(), cls
-    for meth in ("submit", "step", "run", "poll", "stats"):
+    for meth in ("submit", "step", "run", "poll", "stats", "timings"):
         doc = inspect.getdoc(getattr(ServeEngine, meth)) or ""
         assert doc.strip(), f"ServeEngine.{meth} undocumented"
     # the proposer protocol is the extension contract — every lifecycle

@@ -97,6 +97,11 @@ def test_forward_backward_kernels_compile_for_v5e(
     ).compile()
     # forward + the dq and dk/dv passes of the backward
     assert _kernel_calls(compiled) == 3
+    # each backward pass carries its own name, and not the forward's
+    names = [line.split("=")[0] for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    for kernel in ("taylor_attention_kernel", "taylor_bwd_dq", "taylor_bwd_dkv"):
+        assert sum(kernel in n for n in names) == 1, (kernel, names)
 
 
 def test_kernels_compile_per_shard_on_a_data_mesh(
