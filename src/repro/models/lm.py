@@ -440,13 +440,56 @@ def lm_verify_chunk(
     return _logits(params, x, cfg), new
 
 
+def _layer_cache(stack, g: Array, r: Array):
+    """Layer ``(g, r)``'s cache read out of a run's stacked caches
+    (leaves ``[n_groups, run_len, ...]``)."""
+    return jax.tree.map(
+        lambda s: jax.lax.dynamic_slice(
+            s, (g, r) + (0,) * (s.ndim - 2), (1, 1) + s.shape[2:]
+        )[0, 0],
+        stack,
+    )
+
+
+def _set_layer_cache(stack, new, g: Array, r: Array):
+    """``stack`` with layer ``(g, r)``'s cache replaced by ``new``: a
+    dynamic-update-slice per leaf, which XLA does in place on a carried
+    buffer."""
+    return jax.tree.map(
+        lambda s, n: jax.lax.dynamic_update_slice(
+            s, n[None, None], (g, r) + (0,) * (s.ndim - 2)
+        ),
+        stack,
+        new,
+    )
+
+
+def _keep_rows(keep: Optional[Array], new, old):
+    """``new`` where ``keep`` (``[b]`` bool) is True, ``old`` elsewhere —
+    every leaf of a layer's cache carries the batch axis in front."""
+    if keep is None:
+        return new
+    return jax.tree.map(
+        lambda n, o: jnp.where(keep.reshape((-1,) + (1,) * (n.ndim - 1)), n, o),
+        new,
+        old,
+    )
+
+
 def lm_decode_step(
-    params, token_t: Array, caches, pos, cfg: ModelConfig
+    params, token_t: Array, caches, pos, cfg: ModelConfig,
+    keep: Optional[Array] = None,
 ) -> Tuple[Array, Any]:
     """One decode step.  token_t: [b] int32; pos: scalar or [b] int32
     (0-based position of this token — a vector gives every batch row /
-    serving slot its own position).  Returns (logits [b, vocab], new
-    caches)."""
+    serving slot its own position).  ``keep`` (optional ``[b]`` bool)
+    limits the cache update to the rows where it is True: the other rows'
+    caches come back bit for bit as they went in.  Returns (logits
+    [b, vocab], new caches).
+
+    The stacked group caches travel through the layer scans as carry and
+    each layer updates its own slice in place, so a step moves each
+    layer's state once and never copies the whole stack."""
     dtype = jnp.dtype(cfg.dtype)
     x_t = embed_apply(params["embed"], token_t, dtype)
     if cfg.embed_scale:
@@ -462,27 +505,33 @@ def lm_decode_step(
 
     runs = _cfg_runs(cfg)
 
-    def group_body(x_t, xs):
-        group_params, group_caches = xs
+    def group_body(carry, xs):
+        x_t, group_caches = carry
+        group_params, g = xs
         new_caches = []
         for j, (kind, rcfg, rl) in enumerate(runs):
-            def run_body(x_t, step_xs, kind=kind, rcfg=rcfg):
-                p, c = step_xs
-                x_t, c = block_decode(
+            def run_body(carry, step_xs, kind=kind, rcfg=rcfg):
+                x_t, stack = carry
+                p, r = step_xs
+                c = _layer_cache(stack, g, r)
+                x_t, new = block_decode(
                     shared if kind == "shared_attn" else p, kind, x_t, c, rcfg, pos
                 )
-                return x_t, c
+                stack = _set_layer_cache(stack, _keep_rows(keep, new, c), g, r)
+                return (x_t, stack), None
 
             rp = None if kind == "shared_attn" else group_params[f"r{j}"]
-            x_t, run_caches = jax.lax.scan(
-                run_body, x_t, (rp, group_caches[j]), length=rl
+            (x_t, stack), _ = jax.lax.scan(
+                run_body, (x_t, group_caches[j]),
+                (rp, jnp.arange(rl, dtype=jnp.int32)), length=rl,
             )
-            new_caches.append(run_caches)
-        return x_t, tuple(new_caches)
+            new_caches.append(stack)
+        return (x_t, tuple(new_caches)), None
 
     if blocks["group"]:
-        x_t, group_caches = jax.lax.scan(
-            group_body, x_t, (blocks["group"], caches["group"])
+        (x_t, group_caches), _ = jax.lax.scan(
+            group_body, (x_t, caches["group"]),
+            (blocks["group"], jnp.arange(cfg.n_groups, dtype=jnp.int32)),
         )
     else:
         group_caches = ()
@@ -490,8 +539,9 @@ def lm_decode_step(
     tail_cfg = cfg.layer_cfg(cfg.attention)
     for i, kind in enumerate(cfg.tail):
         p = shared if kind == "shared_attn" else blocks["tail"][f"t{i}"]
-        x_t, c = block_decode(p, kind, x_t, caches["tail"][i], tail_cfg, pos)
-        tail_caches.append(c)
+        c = caches["tail"][i]
+        x_t, new = block_decode(p, kind, x_t, c, tail_cfg, pos)
+        tail_caches.append(_keep_rows(keep, new, c))
     logits = _logits(params, x_t[:, None, :], cfg)[:, 0, :]
     new = {"group": group_caches, "tail": tuple(tail_caches), "kv_src": kv_src}
     return logits, new

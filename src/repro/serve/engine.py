@@ -227,11 +227,18 @@ def _decode_scan_fn(cfg: ModelConfig, steps: int, sampling: bool, max_top_k: int
         stored = caches
         if codec is not None:
             caches = codec.decode(stored)
-        caches_in, active_in = caches, active
+        # Slots inactive at DISPATCH time keep their pre-dispatch state bit
+        # for bit: every step updates only the rows of ``active_in``.  A
+        # speculative slot advanced by a verify this block is LIVE while
+        # excluded from the decode mask, so its state must not churn.  The
+        # mask is applied inside each layer's in-place update, so no
+        # snapshot of the whole state is held across the dispatch.
+        active_in = active
 
         def body(carry, _):
             token, caches, pos, active, rng = carry
-            logits, caches = lm_decode_step(params, token, caches, pos, cfg)
+            logits, caches = lm_decode_step(params, token, caches, pos, cfg,
+                                            keep=active_in)
             if sampling:
                 rng, sub = jax.random.split(rng)
                 nxt = sample_tokens(
@@ -251,15 +258,6 @@ def _decode_scan_fn(cfg: ModelConfig, steps: int, sampling: bool, max_top_k: int
         (token, caches, pos, active, rng), (toks, mask) = jax.lax.scan(
             body, (token, caches, pos, active, rng), None, length=steps
         )
-        # Slots inactive at DISPATCH time keep their pre-dispatch state
-        # bit-identically.  Before speculative decoding, inactive regions
-        # were always dead (free/retired) and their scan churn harmless;
-        # a speculative slot advanced by a verify this block is LIVE while
-        # excluded from the decode mask, so the churn must be undone.
-        # One fused select per leaf per dispatch (not per scan step).
-        from repro.serve.slots import select_slots  # noqa: PLC0415
-
-        caches = select_slots(active_in, caches, caches_in)
         if codec is not None:
             caches = codec.encode(caches, stored)
         return caches, token, pos, active, rng, toks, mask

@@ -1,6 +1,8 @@
 """Serving engine: generation, taylor-vs-kv cache behaviour, long context,
 continuous batching (slot admission/eviction, scan-decode parity)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,7 +10,7 @@ import pytest
 
 from repro.configs import get_reduced
 from repro.models import lm_init
-from repro.models.lm import lm_apply, lm_init_caches, lm_prefill
+from repro.models.lm import _cfg_runs, lm_apply, lm_init_caches, lm_prefill
 from repro.serve import Request, ServeEngine, generate, generate_loop
 
 
@@ -218,3 +220,96 @@ def test_vlm_generation_uses_image(rng):
     t1 = generate(params, {"tokens": prompt, "image_embeds": img1}, cfg, steps=4)
     t2 = generate(params, {"tokens": prompt, "image_embeds": img2}, cfg, steps=4)
     assert not np.array_equal(np.asarray(t1), np.asarray(t2))
+
+
+def _long_run_hybrid():
+    """Two groups of a taylor run of length 2 then a window layer: the
+    layer scans index both the group and the position inside a run."""
+    return get_reduced("qwen2-1.5b").replace(
+        pattern=("attn",) * 3, n_groups=2, attention="taylor",
+        attn_window=16, attention_schedule={2: "softmax_window"},
+    )
+
+
+@pytest.mark.parametrize("arch", ["taylor", "long_run_hybrid"])
+def test_decode_scan_holds_inactive_slots_bit_for_bit(arch, rng):
+    """A decode dispatch with a slot inactive at dispatch: that slot's
+    cache leaves come back bit-identical to its input, and the active
+    slots emit the tokens of a per-token ``decode_step`` loop."""
+    from repro.serve.engine import decode_scan, decode_step  # noqa: PLC0415
+
+    cfg = (get_reduced("qwen2-1.5b") if arch == "taylor"
+           else _long_run_hybrid())
+    if arch != "taylor":
+        assert any(rl > 1 for _, _, rl in _cfg_runs(cfg))
+    params = lm_init(jax.random.PRNGKey(0), cfg)
+    slots, n, steps = 3, 8, 5
+    prompt = jnp.asarray(rng.integers(0, cfg.vocab, (slots, n)), jnp.int32)
+    logits, caches = lm_prefill(params, {"tokens": prompt}, cfg, n_max=32)
+    token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    pos = jnp.full((slots,), n, jnp.int32)
+    active = jnp.asarray([True, False, True])
+    before = jax.tree.map(np.asarray, caches)
+
+    # the per-token loop advances every row (no keep mask)
+    loop_caches, loop_tok, loop_toks = caches, token, []
+    for i in range(steps):
+        lg, loop_caches = decode_step(params, loop_tok, loop_caches, pos + i, cfg)
+        loop_tok = jnp.argmax(lg, axis=-1).astype(jnp.int32)
+        loop_toks.append(np.asarray(loop_tok))
+    loop_toks = np.stack(loop_toks)
+
+    out, _, out_pos, _, _, toks, mask = decode_scan(
+        params, caches, token, pos, active,
+        jnp.zeros((slots,), jnp.float32), jnp.zeros((slots,), jnp.int32),
+        jnp.full((slots,), -1, jnp.int32), jax.random.PRNGKey(1),
+        cfg, steps, sampling=False, max_top_k=0,
+    )
+    keep = np.asarray(active)
+    np.testing.assert_array_equal(np.asarray(mask), np.tile(keep, (steps, 1)))
+    np.testing.assert_array_equal(np.asarray(toks)[:, keep], loop_toks[:, keep])
+    np.testing.assert_array_equal(np.asarray(out_pos), np.where(keep, n + steps, n))
+    for axis, part in ((2, "group"), (0, "tail")):
+        for got, was in zip(jax.tree.leaves(out[part]),
+                            jax.tree.leaves(before[part])):
+            np.testing.assert_array_equal(
+                np.take(np.asarray(got), [1], axis=axis),
+                np.take(was, [1], axis=axis),
+            )
+
+
+@pytest.mark.parametrize("arch", ["taylor", "long_run_hybrid"])
+def test_decode_step_keep_mask(arch, rng):
+    """``keep=None`` (the speculative draft loop's call) and an all-True
+    ``keep`` give bit-identical logits and caches; a partial ``keep``
+    changes nothing in the kept rows and leaves the other rows' caches
+    exactly as they went in."""
+    from repro.models.lm import lm_decode_step  # noqa: PLC0415
+
+    cfg = (get_reduced("qwen2-1.5b") if arch == "taylor"
+           else _long_run_hybrid())
+    params = lm_init(jax.random.PRNGKey(0), cfg)
+    b, n = 3, 8
+    prompt = jnp.asarray(rng.integers(0, cfg.vocab, (b, n)), jnp.int32)
+    _, caches = lm_prefill(params, {"tokens": prompt}, cfg, n_max=32)
+    tok = prompt[:, -1]
+    pos = jnp.full((b,), n, jnp.int32)
+    step = jax.jit(functools.partial(lm_decode_step, cfg=cfg))
+    ref_logits, ref = step(params, tok, caches, pos)
+    all_logits, all_kept = step(params, tok, caches, pos,
+                                keep=jnp.ones((b,), bool))
+    np.testing.assert_array_equal(np.asarray(all_logits), np.asarray(ref_logits))
+    for x, y in zip(jax.tree.leaves(all_kept), jax.tree.leaves(ref)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+    keep = np.asarray([False, True, True])
+    part_logits, part = step(params, tok, caches, pos, keep=jnp.asarray(keep))
+    np.testing.assert_array_equal(np.asarray(part_logits), np.asarray(ref_logits))
+    for axis, name in ((2, "group"), (0, "tail")):
+        for got, new, old in zip(jax.tree.leaves(part[name]),
+                                 jax.tree.leaves(ref[name]),
+                                 jax.tree.leaves(caches[name])):
+            want = np.where(
+                keep.reshape([-1 if a == axis else 1 for a in range(got.ndim)]),
+                np.asarray(new), np.asarray(old))
+            np.testing.assert_array_equal(np.asarray(got), want)

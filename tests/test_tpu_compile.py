@@ -138,3 +138,54 @@ def test_kernels_compile_per_shard_on_a_data_mesh(
     with dist.sharding_rules(mesh, dist.rules_for_mesh(mesh)):
         lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv)
     assert _kernel_calls(lowered.compile()) == 3
+
+
+def test_decode_scan_updates_the_moment_stack_in_place(
+    one_chip, no_persistent_cache
+):
+    """The serve engine's 16-step decode dispatch at qwen2-1.5b widths
+    (28 layers, 6 slots) updates the stacked moment state in place: no
+    copy of the whole stack and no fresh buffer for it, per step or per
+    dispatch, and next to no temporaries beside the donated state."""
+    from repro.configs import get_config  # noqa: PLC0415
+    from repro.models.lm import lm_init  # noqa: PLC0415
+    from repro.serve.engine import _jitted_decode_scan  # noqa: PLC0415
+    from repro.serve.slots import init_slot_caches  # noqa: PLC0415
+
+    cfg = get_config("qwen2-1.5b")
+    slots, n_max = 6, 9216
+
+    def on_chip(x, dtype=None):
+        return jax.ShapeDtypeStruct(x.shape, dtype or x.dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda x: on_chip(x, jnp.bfloat16),
+        jax.eval_shape(lambda: lm_init(jax.random.PRNGKey(0), cfg)),
+    )
+    caches = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: init_slot_caches(cfg, slots, n_max, jnp.dtype(cfg.dtype))))
+
+    def per_slot(dtype):
+        return jax.ShapeDtypeStruct((slots,), dtype, sharding=one_chip)
+
+    compiled = _jitted_decode_scan(cfg, 16, False, 0).lower(
+        params, caches, per_slot(jnp.int32), per_slot(jnp.int32),
+        per_slot(jnp.bool_), per_slot(jnp.float32), per_slot(jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip),
+    ).compile()
+
+    # the stacked second moment, [n_groups, run, slots, hk, d, d, d] f32
+    s2 = max(jax.tree.leaves(caches["group"]), key=lambda x: x.size)
+    stack = f"f32[{','.join(map(str, s2.shape))}]"
+    assert stack == "f32[28,1,6,2,128,128,128]"
+    text = compiled.as_text()
+    assert stack in text
+    whole_stack = [
+        line.strip()[:160] for line in text.splitlines()
+        if f"= {stack}" in line
+        and (" copy(" in line or 'custom_call_target="AllocateBuffer"' in line)
+    ]
+    assert not whole_stack, whole_stack
+    temp_gib = compiled.memory_analysis().temp_size_in_bytes / 2**30
+    assert temp_gib < 0.5, temp_gib
