@@ -5,10 +5,12 @@ and entries; no file here changes.
 
 * ``configs/<config>.json``: the model's published sizes and the system's
   settings (``system.entry`` names the module under ``entries/`` that
-  runs it);
+  runs it, ``reference`` the plain reference under ``reference/``);
 * ``traffic/<mix>.json``: parameters for ``generator.py`` or the entry;
 * ``limits/<workload>.json``: the limit of each number the check compares;
-* ``metrics/<metric>.py``: ``read(run) -> float | None`` for one metric.
+* ``metrics/<metric>.py``: ``read(run) -> float | None`` for one metric;
+* ``reference/<module>.py``: the plain reference of a configuration's
+  architecture (the interface the checks use is in ``reference/model.py``).
 """
 
 from __future__ import annotations
@@ -16,7 +18,9 @@ from __future__ import annotations
 import importlib.util
 import json
 import pathlib
-from typing import Callable, List, Optional
+import sys
+from types import ModuleType
+from typing import Callable, Dict, List, Optional
 
 BENCH_DIR = pathlib.Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
@@ -30,6 +34,7 @@ class Catalog:
         self.root = pathlib.Path(root)
         self.dir = pathlib.Path(bench_dir) if bench_dir else BENCH_DIR
         self.doc = json.loads((self.root / "BENCHMARK.json").read_text())
+        self._references: Dict[str, ModuleType] = {}
 
     def _json(self, kind: str, name: str) -> dict:
         path = self.dir / kind / f"{name}.json"
@@ -69,11 +74,25 @@ class Catalog:
 
     def reader(self, metric: str) -> Callable:
         """``read(run)`` of ``metrics/<metric>.py``."""
-        path = self.dir / "metrics" / f"{metric}.py"
+        return self._module("metrics", metric).read
+
+    def reference(self, config: dict) -> ModuleType:
+        """The plain reference module that a configuration names under
+        ``reference``: ``reference/<module>.py``, ``model`` where it names
+        none.  Loaded once per catalog, so that its jitted functions
+        compile once in a process."""
+        name = config.get("reference", "model")
+        if name not in self._references:
+            self._references[name] = self._module("reference", name)
+        return self._references[name]
+
+    def _module(self, kind: str, name: str) -> ModuleType:
+        path = self.dir / kind / f"{name}.py"
         if not path.is_file():
-            raise KeyError(f"no reader {path.relative_to(self.root)}")
-        spec = importlib.util.spec_from_file_location(
-            f"bench_metric_{metric.replace('.', '_').replace('-', '_')}", path)
+            raise KeyError(f"no {kind} module {path.relative_to(self.root)}")
+        mod_name = f"bench_{kind}_{name.replace('.', '_').replace('-', '_')}"
+        spec = importlib.util.spec_from_file_location(mod_name, path)
         mod = importlib.util.module_from_spec(spec)
+        sys.modules[mod_name] = mod  # dataclasses look their module up there
         spec.loader.exec_module(mod)
-        return mod.read
+        return mod

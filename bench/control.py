@@ -41,16 +41,11 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 def serve_control(run) -> dict:
     import numpy as np
 
-    from bench import generator
     from bench.entries import serve
-    from bench.reference.model import FP8, Spec
 
     recs = run.data["records"]
-    picked = serve.checked(recs)
-    shp = generator.shapes(run.traffic)
-    _, lower = serve.reference_gaps(
-        Spec.from_config(run.config), run.data["params"], recs, picked,
-        shp["max_prompt"] + shp["max_new_tokens"], shp["max_new_tokens"], dtype=FP8)
+    _, lower = serve.reference_gaps(run, run.data["params"], recs, serve.checked(recs),
+                                    dtype=run.reference.FP8)
     low = np.concatenate(lower)
     return {"control_mean_gap": float(low.mean()), "control_max_gap": float(low.max())}
 
@@ -161,15 +156,17 @@ def main(argv=None) -> int:
         if rate is not None:
             traffic = dict(traffic, arrivals=dict(traffic["arrivals"], rate_per_s=rate))
         run = harness.Run(workload=cell, config=config, traffic=traffic, seed=seed,
-                          seconds=args.seconds, peak=work.peaks(devices[0].device_kind))
+                          seconds=args.seconds, peak=work.peaks(devices[0].device_kind),
+                          reference=cat.reference(config))
         entry = serve if config["system"]["entry"] == "serve" else train
         entry.run_cell(run, limits, clock, harness.Tracer(False), time.monotonic(), devices)
         row = {"seed": seed, "failed": run.failed, "attempted": run.attempted,
                "window_s": run.window_s, "setup_s": run.setup_s,
                **{c.name: c.value for c in run.checks}}
         if entry is serve:
-            row.update(served(run), {m["name"]: cat.reader(m["name"])(run)
-                                     for m in cat.metrics(args.workload, per_layer=False)})
+            row.update(served(run))
+            row.update({m["name"]: cat.reader(m["name"])(run)
+                        for m in cat.metrics(args.workload, per_layer=False)})
         if rate is not None:
             row.update(rate=rate, queue_at_close=[s["queue"] for s in run.data["steps"]
                                                   if s["t1"] <= run.data["t_end"]][-1:])

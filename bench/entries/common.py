@@ -10,29 +10,54 @@ import jax.numpy as jnp
 import numpy as np
 
 
+# published key of a configuration's ``model`` block -> the program's
+# ``ModelConfig`` field that holds it ("a.b" reads ``cfg.a.b``).  A file adds
+# the keys of its own architecture under ``compared``.
+COMPARED = {
+    "hidden_size": "d_model", "num_hidden_layers": "n_layers",
+    "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
+    "intermediate_size": "d_ff", "vocab_size": "vocab",
+    "tie_word_embeddings": "tie_embeddings", "hidden_act": "act",
+    "rms_norm_eps": "norm_eps", "rope_theta": "rope_theta",
+}
+# constants the file sets in the program's config rather than checks
+APPLIED = ("rms_norm_eps", "rope_theta")
+
+
+def _field(cfg, path: str):
+    for name in path.split("."):
+        cfg = getattr(cfg, name, None)
+    return cfg
+
+
 def model_config(doc: dict):
     """The program's ``ModelConfig`` for a configuration file: the named
-    preset with the file's constants and the system's overrides.  Refuses
-    a preset whose sizes differ from the file's."""
+    preset with the file's constants and the system's overrides.
+
+    Every key of the file's ``model`` block is compared with the program's
+    field that ``COMPARED`` or the file's own ``compared`` maps it to, or
+    is listed, with the reason, under the file's ``not_compared``.  Refuses
+    a preset whose values differ from the file's, and a key that is
+    neither mapped nor listed."""
     from repro.configs import get_config  # noqa: PLC0415
 
     m, sysd, att = doc["model"], doc["system"], doc["attention"]
-    cfg = get_config(sysd["model"]).replace(
-        norm_eps=float(m["rms_norm_eps"]), rope_theta=float(m["rope_theta"]),
-        **sysd.get("overrides", {}),
-    )
-    want = {
-        "d_model": m["hidden_size"], "n_layers": m["num_hidden_layers"],
-        "n_heads": m["num_attention_heads"], "n_kv_heads": m["num_key_value_heads"],
-        "d_ff": m["intermediate_size"], "vocab": m["vocab_size"],
-        "tie_embeddings": m["tie_word_embeddings"], "attention": att["kind"],
-    }
-    got = {k: getattr(cfg, k) for k in want}
-    got["attention"] = cfg.attention
+    fields = dict(COMPARED, **doc.get("compared", {}))
+    applied = {fields[k]: float(m[k]) for k in APPLIED if k in m}
+    cfg = get_config(sysd["model"]).replace(**applied, **sysd.get("overrides", {}))
+    skip = doc.get("not_compared", {})
+    unknown = sorted(set(m) - set(fields) - set(skip))
+    if unknown:
+        raise ValueError(f"model keys {unknown} are neither compared nor listed under "
+                         "not_compared")
+    want = {k: v for k, v in m.items() if k in fields and k not in skip}
+    got = {k: _field(cfg, fields[k]) for k in want}
+    want["attention"], got["attention"] = att["kind"], cfg.attention
     if cfg.taylor.order != att["order"] or cfg.taylor.alpha != att["alpha"]:
         raise ValueError(f"program taylor config {cfg.taylor} differs from {att}")
     if got != want:
-        raise ValueError(f"program config {got} differs from the file's {want}")
+        diff = {k: (want[k], got[k]) for k in want if got[k] != want[k]}
+        raise ValueError(f"program config differs from the file's (file, program): {diff}")
     return cfg
 
 

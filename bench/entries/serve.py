@@ -11,8 +11,9 @@ the window are then drained, for at most the mix's ``grace_s``; one that
 has not finished by then, or ends in another status than OK, is failed.
 
 Check: every request that finished is run through the plain reference
-with its served tokens (teacher forcing): some thousands of served
-tokens, from prompts of every length the mix sends, whole and chunked.
+that the configuration names, with its served tokens (teacher forcing):
+some thousands of served tokens, from prompts of every length the mix
+sends, whole and chunked.
 For each served token the gap by which its reference logit lies below
 the reference's best is read; ``mean_gap``, their mean over all served
 tokens, is compared with its limit.  A greedy engine that served what the
@@ -96,7 +97,7 @@ def run_cell(run, limits, clock, tracer, t_start, devices):
     eng_cfg = sysd["engine"]
     cfg = model_config(run.config)
     dtype = jnp.dtype(sysd["weights_dtype"])
-    params = weights.make(weights.layout(cfg, dtype), run.seed, dtype)
+    params = weights.make(weights.layout(cfg, dtype), run.seed)
     jax.block_until_ready(params)
     reqs = generator.make_requests(mix, run.seed, run.seconds, cfg.vocab)
     eng = ServeEngine(params, cfg, **eng_cfg)
@@ -206,12 +207,15 @@ def pad_length(n: int, most: int) -> int:
     return min(most, max(MIN_PAD, _next_pow2(n)))
 
 
-def reference_gaps(spec, params, records, picked, most, out_len, dtype=None):
-    """For each picked request, the reference's gap below its best logit of
-    every served token; with ``dtype`` also the gap of the token that the
-    reference computed in ``dtype`` puts first.  Returns two lists."""
-    from bench.reference import model as ref  # noqa: PLC0415
-
+def reference_gaps(run, params, records, picked, dtype=None):
+    """For each picked request, the gap below its best logit of every
+    served token in the reference that the configuration names; with
+    ``dtype`` also the gap of the token that the reference computed in
+    ``dtype`` puts first.  Returns two lists."""
+    ref = run.reference
+    spec = ref.Spec.from_config(run.config)
+    shp = generator.shapes(run.traffic)
+    most, out_len = shp["max_prompt"] + shp["max_new_tokens"], shp["max_new_tokens"]
     served, lower = [], []
     for i in picked:
         p, t = records[i]["prompt"], records[i]["tokens"]
@@ -234,18 +238,14 @@ def checked(records: list) -> list:
 
 
 def check(run, params, records, limits) -> list:
-    from bench.reference.model import Spec  # noqa: PLC0415
-
     picked = checked(records)
     if not picked:
         return [Check("mean_gap", float("nan"), limits["mean_gap"])]
-    shp = generator.shapes(run.traffic)
-    spec = Spec.from_config(run.config)
-    served, _ = reference_gaps(spec, params, records, picked,
-                               shp["max_prompt"] + shp["max_new_tokens"],
-                               shp["max_new_tokens"])
+    t = time.monotonic()
+    served, _ = reference_gaps(run, params, records, picked)
     gaps = np.concatenate(served)
-    log(f"[check] {len(picked)} requests, {gaps.size} served tokens; "
+    log(f"[check] {len(picked)} requests, {gaps.size} served tokens in "
+        f"{time.monotonic() - t:.1f} s; "
         f"prompts {sorted(len(records[i]['prompt']) for i in picked)}; "
         f"widest gap {gaps.max():.5f}; reference argmax agreement {(gaps == 0).mean():.5f}")
     return [Check("mean_gap", float(gaps.mean()), limits["mean_gap"])]

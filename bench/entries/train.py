@@ -69,7 +69,7 @@ def run_cell(run, limits, clock, tracer, t_start, devices):
     shapes = {k: jax.ShapeDtypeStruct((batch, seq), jnp.int32) for k in ("tokens", "labels")}
     state, step_fn, state_ns, batch_ns = make_sharded_state_and_step(
         cfg, optimizer(opt), mesh, rules, shapes, seed=run.seed & 0x7FFFFFFF)
-    params = weights.make(weights.layout(cfg, jnp.float32), run.seed, jnp.float32)
+    params = weights.make(weights.layout(cfg, jnp.float32), run.seed)
     state = state._replace(params=jax.device_put(params, state_ns.params))
     del params
 
@@ -135,22 +135,22 @@ def run_cell(run, limits, clock, tracer, t_start, devices):
 
 
 def reference_steps(run, task, steps: int, rows=None, dtype=None):
-    """The plain reference through ``steps`` AdamW steps from the seeded
-    weights on the rows the program saw: ``(losses, grad_norms of step 1,
-    change_norms after the last step)``.  ``rows`` limits each batch to
-    its first rows; ``dtype`` runs the reference in another precision
-    (both for the control)."""
+    """The plain reference that the configuration names, through ``steps``
+    AdamW steps from the seeded weights on the rows the program saw:
+    ``(losses, grad_norms of step 1, change_norms after the last step)``.
+    ``rows`` limits each batch to its first rows; ``dtype`` runs the
+    reference in another precision (both for the control)."""
     import jax  # noqa: PLC0415
     import jax.numpy as jnp  # noqa: PLC0415
 
     from bench.reference import adamw  # noqa: PLC0415
-    from bench.reference import model as ref  # noqa: PLC0415
 
+    ref = run.reference
     dtype = dtype or jnp.float32
     spec = ref.Spec.from_config(run.config)
     opt = run.config["system"]["optimizer"]
     cfg = run.data["cfg"]
-    p0 = weights.make(weights.layout(cfg, jnp.float32), run.seed, jnp.float32)
+    p0 = weights.make(weights.layout(cfg, jnp.float32), run.seed)
     params = jax.tree.map(lambda x: x.astype(dtype), p0)
     state = adamw.init(params)
     losses, grad_norms = [], None

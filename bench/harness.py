@@ -150,7 +150,8 @@ class Check:
 @dataclasses.dataclass
 class Run:
     """What a run leaves for the metric readers.  Entries fill ``data``
-    with their own records; see ``bench/entries``."""
+    with their own records; see ``bench/entries``.  ``reference`` is the
+    plain reference module the configuration names."""
 
     workload: dict
     config: dict
@@ -158,6 +159,7 @@ class Run:
     seed: int
     seconds: float
     peak: dict
+    reference: Any = None
     setup_s: float = 0.0
     window_s: float = 0.0
     attempted: int = 0
@@ -180,9 +182,12 @@ def parse_args(argv):
 
 def configure_jax_cache() -> None:
     """The persistent compilation cache, at one fixed place in the
-    checkout; every program is kept, however fast it compiled.  Set before
-    JAX starts, so the program's own cache helper takes the same one.  The
-    TPU runtime's logs go under ``TMPDIR``, not to a fixed path."""
+    checkout; every program is kept, however fast it compiled and however
+    large the cache grows: a size limit set in the environment would evict
+    programs that the next run reads back, and a cell whose programs pass
+    it would compile in every run.  Set before JAX starts, so the program's
+    own cache helper takes the same one.  The TPU runtime's logs go under
+    ``TMPDIR``, not to a fixed path."""
     os.environ["JAX_COMPILATION_CACHE_DIR"] = str(CACHE_DIR)
     os.environ.setdefault("TPU_LOG_DIR", os.path.join(tempfile.gettempdir(), "tpu_logs"))
     import jax  # noqa: PLC0415
@@ -190,6 +195,7 @@ def configure_jax_cache() -> None:
     jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    jax.config.update("jax_compilation_cache_max_size", -1)
 
 
 def find_devices(chips: int, require_tpu: bool = True):
@@ -249,7 +255,7 @@ def execute(args, cat: Catalog, t_start: float,
     tracer = Tracer(bool(args.trace))
     tracer.prime()
     run = Run(workload=cell, config=config, traffic=traffic, seed=args.seed,
-              seconds=args.seconds, peak=peak)
+              seconds=args.seconds, peak=peak, reference=cat.reference(config))
     entry = importlib.import_module(f"bench.entries.{config['system']['entry']}")
     entry.run_cell(run, limits=limits, clock=clock, tracer=tracer,
                    t_start=t_start, devices=devices)

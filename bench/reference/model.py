@@ -22,6 +22,23 @@ weights are seeded, not trained.  ``dtype`` lets the same code run in a
 lower precision, which is what the benchmark's controls do: ``bfloat16``
 computes everything in bfloat16; ``FP8`` rounds every weight to float8
 (e4m3, one scale per tensor) and computes in bfloat16.
+
+This is the default reference: a configuration file names another module
+of this directory under ``"reference"`` where its architecture needs one
+(``bench/catalog.py``).  Every reference module gives what the checks
+use (``bench/entries``, ``bench/control.py``):
+
+* ``Spec.from_config(doc)``: a hashable spec of the sizes and constants,
+  from the configuration file; it raises where the module cannot compute
+  the file's architecture;
+* ``logits_at(spec, params, tokens, idx, dtype=float32)``: float32 logits
+  ``[len(idx), vocab]`` at positions ``idx`` of one sequence (serving);
+* ``row_loss_and_grad(spec, params, tokens, labels, dtype=float32)``:
+  ``(loss, grads)`` of one sequence, grads float32 (training);
+* ``FP8``: the ``dtype`` of the float8 control.
+
+``params`` is the benchmark's seeded tree (``bench/weights.py``) in the
+program's layout.
 """
 
 from __future__ import annotations

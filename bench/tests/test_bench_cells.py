@@ -1,6 +1,8 @@
-"""Both cells run end to end on the CPU at a tiny size, past the look for a
-chip: sound runs come out correct, and the control and each fault a cell
-can have come out not correct (``cpu_cell.py``).
+"""Every cell of ``BENCHMARK.json`` runs end to end on the CPU at a tiny
+size, past the look for a chip: sound runs come out correct, and the
+control and each fault a cell can have come out not correct
+(``cpu_cell.py``).  The cells and their cuts come from ``tiny.py``; a cell
+without a cut fails here, naming itself.
 
 Faults: serving, a token altered where it is produced; training, a step
 that returns its state unchanged, and half of each batch left out.  The
@@ -38,7 +40,25 @@ def _run(root, workload, fault=""):
     return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
-@pytest.mark.parametrize("workload", ["tiny-serve.chat", "tiny-train.train"])
+CELLS = tiny.cells()
+REAL = json.loads((tiny.BENCH.parent / "BENCHMARK.json").read_text())["workloads"]
+
+
+@pytest.mark.parametrize("index", range(len(REAL)), ids=[w["name"] for w in REAL])
+def test_every_cell_has_a_tiny_cut(index):
+    """Each real cell maps to a tiny one of the same mix, whose configuration
+    and mix have cuts."""
+    assert len(CELLS) == len(REAL)
+    tiny_name, entry = CELLS[index]
+    assert entry in tiny.FAULTS and tiny_name.endswith("." + REAL[index]["traffic"])
+
+
+def test_a_cell_without_a_tiny_cut_is_named():
+    with pytest.raises(KeyError, match="cell m.unknown has no tiny cut: add bench/tests/tiny_cuts/traffic/unknown.json"):
+        tiny.cut("traffic", "unknown", "m.unknown")
+
+
+@pytest.mark.parametrize("workload", [name for name, _ in CELLS])
 def test_sound_run_is_correct(root, workload):
     result = _run(root, workload)
     assert result["correct"], result["checks"]
@@ -48,10 +68,7 @@ def test_sound_run_is_correct(root, workload):
 
 
 @pytest.mark.parametrize("workload,fault", [
-    ("tiny-serve.chat", "token"), ("tiny-serve.chat", "control"),
-    ("tiny-train.train", "unchanged"), ("tiny-train.train", "half"),
-    ("tiny-train.train", "control"),
-])
+    (name, fault) for name, entry in CELLS for fault in tiny.FAULTS[entry]])
 def test_control_and_faults_are_not_correct(root, workload, fault):
     result = _run(root, workload, fault)
     assert not result["correct"], result["checks"]

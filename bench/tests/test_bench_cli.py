@@ -34,3 +34,17 @@ def test_benchmark_alone_gives_no_result(tmp_path):
     proc = _run(tmp_path, {"PYTHONPATH": ""})
     assert proc.returncode != 0
     assert _no_result(proc.stdout)
+
+
+def test_the_compile_cache_keeps_every_program(tmp_path):
+    """A cache size limit in the environment does not reach the benchmark's
+    cache: a limit would evict programs that the next run reads back."""
+    code = ("import jax; from bench import harness; harness.configure_jax_cache(); "
+            "print(jax.config.jax_compilation_cache_max_size, jax.config.jax_compilation_cache_dir)")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", JAX_COMPILATION_CACHE_MAX_SIZE="200000000",
+               PYTHONPATH=os.pathsep.join([str(ROOT), str(ROOT / "src")]))
+    proc = subprocess.run([sys.executable, "-c", code], cwd=tmp_path, env=env,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    size, path = proc.stdout.split()
+    assert size == "-1" and path == str(ROOT / ".jax_cache")

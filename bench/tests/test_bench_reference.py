@@ -22,7 +22,7 @@ def small(request, tmp_path_factory):
     name = "tiny-serve" if request.param == "qwen2-1.5b" else "tiny-train"
     doc = json.loads((bench / "configs" / f"{name}.json").read_text())
     cfg = model_config(doc).replace(attn_impl="xla", remat="none")
-    params = weights.make(weights.layout(cfg, jnp.float32), 2**31 + 3, jnp.float32)
+    params = weights.make(weights.layout(cfg, jnp.float32), 2**31 + 3)
     return doc, cfg, params
 
 
