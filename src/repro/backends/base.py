@@ -50,7 +50,7 @@ layers resolve a backend PER RUN (``config.schedule_runs``) — mixed
 
 from __future__ import annotations
 
-from typing import Any, Tuple
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -176,6 +176,26 @@ class AttentionBackend:
         position of this token (per batch row / serving slot).  The new
         token attends to itself.  Returns ``(out [b, h, dv], new_cache)``."""
         raise NotImplementedError(self.name)
+
+    def in_place_leaf(self, cfg, stack) -> Optional[str]:
+        """The leaf of a run's stacked decode cache that ``decode_step``
+        takes whole, as a ``state.StackedLeaf``, and updates in place.
+
+        The model's decode scan slices every other leaf to the layer and
+        writes it back; a leaf named here crosses HBM only inside the
+        backend's own pass over it (the Taylor decode kernel's second
+        moment).
+
+        Args:
+          cfg: the run's layer config.
+          stack: the run's stacked cache (leaves ``[n_groups, run_len,
+            b, ...]``; arrays or shapes).
+
+        Returns:
+          The leaf's field name, or ``None`` (default): every leaf is
+          sliced per layer.
+        """
+        return None
 
     def prefill_chunk(self, cache, q: Array, k: Array, v: Array, cfg, pos: Array):
         """Advance a decode state by a CHUNK of prompt tokens in one call.

@@ -68,6 +68,18 @@ class CrossCache(NamedTuple):
     kv: AttnCache
 
 
+class StackedLeaf(NamedTuple):
+    """One leaf of a run's stacked decode cache, handed WHOLE to
+    ``decode_step`` in place of one layer's slice of it (the leaf that
+    ``AttentionBackend.in_place_leaf`` names): the backend advances layer
+    ``layer`` of ``stack`` in place and returns the whole updated stack in
+    that field of the new cache."""
+
+    stack: Array  # [n_groups, run_len, b, ...]
+    layer: Array  # [2] int32: (group, position in the run)
+    keep: Array  # [b] bool: rows that advance; the others stay bit for bit
+
+
 class QuantizedLeaf(NamedTuple):
     """One quantised decode-state tensor + its dequantisation scale.
 

@@ -13,9 +13,14 @@ Two impls, selected by ``ModelConfig.attn_impl``:
 
 ``"auto"`` picks the kernel exactly when it wins: on TPU, inside the
 envelope; everywhere else the XLA scan (off-TPU the interpreter is a
-correctness tool, not an execution engine).  Prefill and decode always
-run the XLA moment-state paths — prefill needs the chunk-scan's
-``return_state`` handoff and decode is state-bound, not compute-bound.
+correctness tool, not an execution engine).  Prefill runs the XLA chunk
+scan, which has the ``return_state`` handoff.  Decode runs the XLA
+recurrence for every moment but one: where the impl resolves to
+"pallas", the order is 2 and the serve scan carries the second moment
+dense f32 off a mesh (``in_place_leaf``), the stacked ``S2`` of the whole
+run goes to the Pallas decode kernel (``kernels/taylor_attention/
+decode.py``), which updates the layer in place and reads it out in one
+pass over it.
 
 Decode/cross state is the O(1) ``TaylorState`` (running moments); states
 of consecutive sequence shards merge by addition, which is what makes the
@@ -27,9 +32,11 @@ from __future__ import annotations
 import dataclasses
 
 import jax
+import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.backends.base import AttentionBackend
+from repro.backends.state import StackedLeaf
 from repro.core import (
     init_taylor_state,
     merge_states,
@@ -40,6 +47,7 @@ from repro.core import (
     taylor_prefill_state,
     taylor_state_read,
 )
+from repro.kernels.taylor_attention.decode import taylor_decode_s2
 from repro.kernels.taylor_attention.ops import taylor_attention_kernel_trainable
 
 Array = jax.Array
@@ -224,8 +232,38 @@ class TaylorBackend(AttentionBackend):
         return o, taylor_prefill_state(k, v, cfg.taylor)
 
     def decode_step(self, cache, q, k, v, cfg, pos):
-        o, cache = taylor_decode_step(cache, q, k, v, cfg.taylor)
-        return o, cache
+        """One token; with ``cache.s2`` a ``StackedLeaf`` (``in_place_leaf``)
+        the decode kernel updates and reads the whole run's S2 in place,
+        and the new cache's ``s2`` is the updated stack."""
+        s2 = cache.s2
+        if not isinstance(s2, StackedLeaf):
+            return taylor_decode_step(cache, q, k, v, cfg.taylor)
+
+        def s2_pass(qg, k, v):
+            return taylor_decode_s2(
+                s2.stack, s2.layer, s2.keep, qg, k, v,
+                interpret=jax.default_backend() != "tpu",
+            )
+
+        return taylor_decode_step(cache, q, k, v, cfg.taylor, s2_pass=s2_pass)
+
+    def in_place_leaf(self, cfg, stack):
+        """``"s2"`` where decode runs the Pallas decode kernel: the impl
+        resolves to "pallas", the order is 2, the stacked ``S2`` is dense
+        f32, and no mesh is active (the partitioner cannot split the
+        kernel, so a sharded engine keeps the XLA recurrence)."""
+        from repro.distributed import api as dist  # noqa: PLC0415 (cycle)
+
+        s2 = getattr(stack, "s2", None)
+        fits = (
+            self.resolve_impl(cfg) == "pallas"
+            and cfg.taylor.order == 2
+            and s2 is not None
+            and s2.ndim == 7
+            and s2.dtype == jnp.float32
+            and dist.active() is None
+        )
+        return "s2" if fits else None
 
     def prefill_chunk(self, cache, q, k, v, cfg, pos):
         """Chunk-scan continuation: one quadratic intra-chunk tile plus the

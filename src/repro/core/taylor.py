@@ -239,7 +239,8 @@ def _chunk_inter(qg: Array, state: TaylorState, cfg: TaylorConfig, a: float):
                 "bkgcf,bkf->bkgc", phi2, state.z2, preferred_element_type=f32
             )
         else:
-            num = num + _quad_num(qg, state.s2, half_a2)
+            if state.s2 is not None:  # None: the decode kernel reads S2
+                num = num + _quad_num(qg, state.s2, half_a2)
             u = jnp.einsum(
                 "bkgcd,bkde->bkgce", qg, state.z2, preferred_element_type=f32
             )
@@ -291,21 +292,22 @@ def _state_update(state: TaylorState, kc: Array, vc: Array, cfg: TaylorConfig) -
         s2 = old(state.s2, 2) + jnp.einsum("bkcf,bkcv->bkfv", phi2, vw)
     elif cfg.order >= 2:
         z2 = old(state.z2, 2) + jnp.einsum("bkcd,bkce->bkde", kw, kc32)
-        # d-tiled: a direct 3-operand einsum materialises [b,k,c,d,e]
-        # (13 GB for a 1600-token cross-attention source at d=128)
-        b, hk, c, d = kc.shape
-        t = _QUAD_TILE if d % _QUAD_TILE == 0 else d
-        parts = []
-        for t0 in range(0, d, t):
-            kk = (kc32[..., t0 : t0 + t, None] * kc32[..., None, :]).reshape(
-                b, hk, c, t * d
-            )
-            parts.append(
-                jnp.einsum("bkcf,bkcv->bkfv", kk, vw).reshape(
-                    b, hk, t, d, vc.shape[-1]
+        if state.s2 is not None:  # None: the decode kernel updates S2
+            # d-tiled: a direct 3-operand einsum materialises [b,k,c,d,e]
+            # (13 GB for a 1600-token cross-attention source at d=128)
+            b, hk, c, d = kc.shape
+            t = _QUAD_TILE if d % _QUAD_TILE == 0 else d
+            parts = []
+            for t0 in range(0, d, t):
+                kk = (kc32[..., t0 : t0 + t, None] * kc32[..., None, :]).reshape(
+                    b, hk, c, t * d
                 )
-            )
-        s2 = old(state.s2, 3) + jnp.concatenate(parts, axis=2)
+                parts.append(
+                    jnp.einsum("bkcf,bkcv->bkfv", kk, vw).reshape(
+                        b, hk, t, d, vc.shape[-1]
+                    )
+                )
+            s2 = old(state.s2, 3) + jnp.concatenate(parts, axis=2)
     return TaylorState(n0=n0, s0=s0, z1=z1, s1=s1, z2=z2, s2=s2)
 
 
@@ -480,21 +482,35 @@ def taylor_decode_step(
     k_t: Array,
     v_t: Array,
     cfg: TaylorConfig,
+    s2_pass=None,
 ):
     """One autoregressive step.
 
     q_t: [b, h, d]; k_t: [b, h_kv, d]; v_t: [b, h_kv, v].
     Returns (out_t [b, h, v], new_state).  The new token attends to itself,
     so the state is updated *before* the read (inclusive causal semantics).
+
+    ``s2_pass`` (optional), ``(qg [b, h_kv, g, d], k_t, v_t) -> (num2
+    [b, h_kv, g, v], s2)``, takes over the second moment: it adds the
+    token to S2 and returns ``(q ⊗ q)·S2`` of the new S2 in one pass (the
+    Pallas decode kernel); ``state.s2`` is then not read here, and the
+    returned state carries the ``s2`` it gave back.
     """
     b, h, d = q_t.shape
     h_kv = k_t.shape[1]
     if cfg.normalize_qk:
         q_t = layernorm_no_affine(q_t).astype(q_t.dtype)
         k_t = layernorm_no_affine(k_t).astype(k_t.dtype)
-    state = _state_update(state, k_t[:, :, None, :], v_t[:, :, None, :], cfg)
     qg = q_t.reshape(b, h_kv, h // h_kv, 1, d)
-    num, den = _chunk_inter(qg, state, cfg, cfg.scale(d))
+    if s2_pass is not None:
+        num2, s2 = s2_pass(qg[:, :, :, 0], k_t, v_t)
+        state = state._replace(s2=None)
+    state = _state_update(state, k_t[:, :, None, :], v_t[:, :, None, :], cfg)
+    a = cfg.scale(d)
+    num, den = _chunk_inter(qg, state, cfg, a)
+    if s2_pass is not None:
+        num = num + 0.5 * a * a * num2[:, :, :, None, :]
+        state = state._replace(s2=s2)
     out = _safe_div(num, den)[:, :, :, 0, :]  # [b, hk, g, v]
     return out.reshape(b, h, v_t.shape[-1]).astype(v_t.dtype), state
 

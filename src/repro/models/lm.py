@@ -17,6 +17,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.backends import resolve_backend
+from repro.backends.state import StackedLeaf
 from repro.distributed.api import constrain
 from repro.models.blocks import (
     block_apply,
@@ -476,6 +478,28 @@ def _keep_rows(keep: Optional[Array], new, old):
     )
 
 
+def _in_place_leaf(kind: str, rcfg: ModelConfig, stack) -> Optional[str]:
+    """The leaf of a run's stacked cache that the run's attention decode
+    takes whole and updates in place (``AttentionBackend.in_place_leaf``),
+    or None."""
+    if kind in ("mamba", "cross"):
+        return None
+    return resolve_backend(rcfg).in_place_leaf(rcfg, stack)
+
+
+def lm_decode_kernel_layers(cfg: ModelConfig) -> int:
+    """How many layers of ``lm_decode_step`` hand a cache leaf whole to
+    their backend's in-place decode (the Taylor decode kernel's stacked
+    S2): every layer of each such run, in every group.  Read under the
+    same mesh context as the decode it describes."""
+    stacks = jax.eval_shape(lambda: lm_init_caches(cfg, 1, 1))["group"]
+    return sum(
+        cfg.n_groups * rl
+        for (kind, rcfg, rl), stack in zip(_cfg_runs(cfg), stacks)
+        if _in_place_leaf(kind, rcfg, stack)
+    )
+
+
 def lm_decode_step(
     params, token_t: Array, caches, pos, cfg: ModelConfig,
     keep: Optional[Array] = None,
@@ -489,7 +513,10 @@ def lm_decode_step(
 
     The stacked group caches travel through the layer scans as carry and
     each layer updates its own slice in place, so a step moves each
-    layer's state once and never copies the whole stack."""
+    layer's state once and never copies the whole stack.  A leaf that the
+    backend updates in place (``_in_place_leaf``: the Taylor decode
+    kernel's S2) goes to it whole, with the layer and ``keep``, and comes
+    back whole: no slice, select or write-back of it here."""
     dtype = jnp.dtype(cfg.dtype)
     x_t = embed_apply(params["embed"], token_t, dtype)
     if cfg.embed_scale:
@@ -504,21 +531,33 @@ def lm_decode_step(
     kv_src = caches.get("kv_src")
 
     runs = _cfg_runs(cfg)
+    rows = keep if keep is not None else jnp.ones(token_t.shape, bool)
 
     def group_body(carry, xs):
         x_t, group_caches = carry
         group_params, g = xs
         new_caches = []
         for j, (kind, rcfg, rl) in enumerate(runs):
-            def run_body(carry, step_xs, kind=kind, rcfg=rcfg):
+            leaf = _in_place_leaf(kind, rcfg, group_caches[j])
+
+            def run_body(carry, step_xs, kind=kind, rcfg=rcfg, leaf=leaf):
                 x_t, stack = carry
                 p, r = step_xs
-                c = _layer_cache(stack, g, r)
+                p = shared if kind == "shared_attn" else p
+                if leaf is None:
+                    c = _layer_cache(stack, g, r)
+                    x_t, new = block_decode(p, kind, x_t, c, rcfg, pos)
+                    stack = _set_layer_cache(stack, _keep_rows(keep, new, c), g, r)
+                    return (x_t, stack), None
+                whole = StackedLeaf(getattr(stack, leaf), jnp.stack([g, r]), rows)
+                rest = stack._replace(**{leaf: None})
+                c = _layer_cache(rest, g, r)
                 x_t, new = block_decode(
-                    shared if kind == "shared_attn" else p, kind, x_t, c, rcfg, pos
+                    p, kind, x_t, c._replace(**{leaf: whole}), rcfg, pos
                 )
-                stack = _set_layer_cache(stack, _keep_rows(keep, new, c), g, r)
-                return (x_t, stack), None
+                small = _keep_rows(keep, new._replace(**{leaf: None}), c)
+                rest = _set_layer_cache(rest, small, g, r)
+                return (x_t, rest._replace(**{leaf: getattr(new, leaf)})), None
 
             rp = None if kind == "shared_attn" else group_params[f"r{j}"]
             (x_t, stack), _ = jax.lax.scan(

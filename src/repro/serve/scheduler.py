@@ -63,6 +63,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.config import ModelConfig
+from repro.models.lm import lm_decode_kernel_layers
 from repro.serve import engine as engine_mod
 from repro.serve import slots as slots_mod
 from repro.serve import speculative as spec_mod
@@ -548,6 +549,7 @@ class ServeEngine:
         self._read_slot = self.state_store.read_slot
         with self._device_ctx():
             self.caches = self.state_store.init_caches()
+            self._decode_kernel_layers = lm_decode_kernel_layers(cfg)
         self._scan_cache: Dict[Any, Any] = {}
         self._partial: Optional[_PartialPrefill] = None
         self._rng = rng if rng is not None else jax.random.PRNGKey(0)
@@ -1570,7 +1572,11 @@ class ServeEngine:
         (order-1 self-draft cost; the n-gram proposer is host-side and
         adds none).
         Gauges: ``blocks`` (decode-block counter), ``queue_depth``
-        (queued + awaiting retry), ``slots_occupied``.
+        (queued + awaiting retry), ``slots_occupied``.  Constant:
+        ``decode_kernel_layers``, the layers of the decode program whose
+        second moment the Pallas decode kernel updates in place (0 where
+        every layer runs the XLA recurrence: off TPU, on a mesh, outside
+        the kernel's envelope).
 
         Returns:
           Dict of counter/gauge name to int value (absent counter = 0).
@@ -1581,6 +1587,7 @@ class ServeEngine:
         out["slots_occupied"] = sum(
             1 for s in self._slots if s.rid is not None
         )
+        out["decode_kernel_layers"] = self._decode_kernel_layers
         return out
 
     def timings(self) -> Dict[str, int]:

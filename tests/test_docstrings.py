@@ -35,6 +35,7 @@ MODULES = (
     "repro.kernels.taylor_attention",
     "repro.kernels.taylor_attention.kernel",
     "repro.kernels.taylor_attention.kernel_bwd",
+    "repro.kernels.taylor_attention.decode",
     "repro.kernels.taylor_attention.ops",
     "repro.kernels.taylor_attention.ref",
 )
@@ -63,6 +64,7 @@ DOCUMENTED_SIGNATURES = {
     ),
     "repro.kernels.taylor_attention.kernel": ("taylor_fwd_pallas",),
     "repro.kernels.taylor_attention.kernel_bwd": ("taylor_bwd_pallas",),
+    "repro.kernels.taylor_attention.decode": ("taylor_decode_s2",),
     "repro.kernels.taylor_attention.ops": (
         "taylor_attention_kernel", "taylor_attention_kernel_trainable",
     ),
@@ -218,9 +220,10 @@ def _module_public_symbols(mod) -> set:
 
 def test_paper_map_covers_public_symbols():
     """docs/paper_map.md must mention every public symbol of
-    core/taylor.py and of both Pallas kernel modules (+ the ops wrapper)
+    core/taylor.py and of the Pallas kernel modules (+ the ops wrapper)
     — the acceptance bar for the paper-to-code map."""
     import repro.core.taylor as taylor
+    import repro.kernels.taylor_attention.decode as decode
     import repro.kernels.taylor_attention.kernel as kernel
     import repro.kernels.taylor_attention.kernel_bwd as kernel_bwd
     import repro.kernels.taylor_attention.ops as ops
@@ -228,7 +231,7 @@ def test_paper_map_covers_public_symbols():
     doc = (pathlib.Path(__file__).parent.parent / "docs" / "paper_map.md"
            ).read_text()
     missing = []
-    for mod in (taylor, kernel, kernel_bwd, ops):
+    for mod in (taylor, kernel, kernel_bwd, decode, ops):
         for name in sorted(_module_public_symbols(mod)):
             if name not in doc:
                 missing.append(f"{mod.__name__}.{name}")

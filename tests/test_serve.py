@@ -231,16 +231,27 @@ def _long_run_hybrid():
     )
 
 
-@pytest.mark.parametrize("arch", ["taylor", "long_run_hybrid"])
+def _arch(arch):
+    """``taylor`` / ``taylor_pallas`` (the decode kernel, interpreted) or
+    the long-run hybrid."""
+    if arch == "long_run_hybrid":
+        return _long_run_hybrid()
+    impl = "pallas" if arch == "taylor_pallas" else "auto"
+    return get_reduced("qwen2-1.5b").replace(attn_impl=impl)
+
+
+ARCHS = ["taylor", "long_run_hybrid", "taylor_pallas"]
+
+
+@pytest.mark.parametrize("arch", ARCHS)
 def test_decode_scan_holds_inactive_slots_bit_for_bit(arch, rng):
     """A decode dispatch with a slot inactive at dispatch: that slot's
     cache leaves come back bit-identical to its input, and the active
     slots emit the tokens of a per-token ``decode_step`` loop."""
     from repro.serve.engine import decode_scan, decode_step  # noqa: PLC0415
 
-    cfg = (get_reduced("qwen2-1.5b") if arch == "taylor"
-           else _long_run_hybrid())
-    if arch != "taylor":
+    cfg = _arch(arch)
+    if arch == "long_run_hybrid":
         assert any(rl > 1 for _, _, rl in _cfg_runs(cfg))
     params = lm_init(jax.random.PRNGKey(0), cfg)
     slots, n, steps = 3, 8, 5
@@ -278,7 +289,7 @@ def test_decode_scan_holds_inactive_slots_bit_for_bit(arch, rng):
             )
 
 
-@pytest.mark.parametrize("arch", ["taylor", "long_run_hybrid"])
+@pytest.mark.parametrize("arch", ARCHS)
 def test_decode_step_keep_mask(arch, rng):
     """``keep=None`` (the speculative draft loop's call) and an all-True
     ``keep`` give bit-identical logits and caches; a partial ``keep``
@@ -286,8 +297,7 @@ def test_decode_step_keep_mask(arch, rng):
     exactly as they went in."""
     from repro.models.lm import lm_decode_step  # noqa: PLC0415
 
-    cfg = (get_reduced("qwen2-1.5b") if arch == "taylor"
-           else _long_run_hybrid())
+    cfg = _arch(arch)
     params = lm_init(jax.random.PRNGKey(0), cfg)
     b, n = 3, 8
     prompt = jnp.asarray(rng.integers(0, cfg.vocab, (b, n)), jnp.int32)
@@ -313,3 +323,25 @@ def test_decode_step_keep_mask(arch, rng):
                 keep.reshape([-1 if a == axis else 1 for a in range(got.ndim)]),
                 np.asarray(new), np.asarray(old))
             np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def test_engine_decode_kernel_gives_the_xla_tokens(rng):
+    """Greedy serving through the Pallas decode kernel (forced, so the
+    interpreter runs it here) emits the tokens of the XLA recurrence, with
+    slots idle inside dispatches; ``stats()`` counts the kernel's layers."""
+    cfg = get_reduced("qwen2-1.5b")  # taylor backend, 3 layers
+    params = lm_init(jax.random.PRNGKey(0), cfg)
+    prompts = [np.asarray(rng.integers(0, cfg.vocab, (n,)), np.int32)
+               for n in (12, 7, 16)]
+    budgets = (9, 4, 6)
+    tokens, layers = {}, {}
+    for impl in ("xla", "pallas"):
+        eng = ServeEngine(params, cfg.replace(attn_impl=impl), max_slots=2,
+                          n_max=64, decode_block=4)
+        rids = [eng.submit(Request(tokens=p, max_new_tokens=b))
+                for p, b in zip(prompts, budgets)]
+        outs = eng.run()
+        tokens[impl] = [outs[r].tolist() for r in rids]
+        layers[impl] = eng.stats()["decode_kernel_layers"]
+    assert tokens["pallas"] == tokens["xla"]
+    assert layers == {"xla": 0, "pallas": cfg.n_layers}

@@ -189,3 +189,66 @@ def test_decode_scan_updates_the_moment_stack_in_place(
     assert not whole_stack, whole_stack
     temp_gib = compiled.memory_analysis().temp_size_in_bytes / 2**30
     assert temp_gib < 0.5, temp_gib
+
+
+def test_decode_scan_runs_the_decode_kernel_on_the_moment_stack(
+    one_chip, no_persistent_cache, monkeypatch
+):
+    """On the chip (auto impl resolves to "pallas") the same 16-step
+    decode dispatch hands each layer's second moment to the Pallas decode
+    kernel: the kernel is the only operation that touches a whole layer's
+    ``S2`` slice (``[6, 2, 128, 128, 128]`` in any dtype), the stack is
+    updated in place with no copy, and the temporaries stay small."""
+    import re  # noqa: PLC0415
+
+    from repro.configs import get_config  # noqa: PLC0415
+    from repro.models.lm import lm_decode_kernel_layers, lm_init  # noqa: PLC0415
+    from repro.serve.engine import _decode_scan_fn  # noqa: PLC0415
+    from repro.serve.slots import init_slot_caches  # noqa: PLC0415
+
+    # compiled for the described chip from this CPU process: steer the
+    # backend's platform check to the TPU branch
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_config("qwen2-1.5b")
+    assert lm_decode_kernel_layers(cfg) == cfg.n_layers == 28
+    slots, n_max = 6, 9216
+
+    def on_chip(x, dtype=None):
+        return jax.ShapeDtypeStruct(x.shape, dtype or x.dtype, sharding=one_chip)
+
+    def per_slot(dtype):
+        return jax.ShapeDtypeStruct((slots,), dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda x: on_chip(x, jnp.bfloat16),
+        jax.eval_shape(lambda: lm_init(jax.random.PRNGKey(0), cfg)),
+    )
+    caches = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: init_slot_caches(cfg, slots, n_max, jnp.dtype(cfg.dtype))))
+    # a jit of its own: the engine's cached one may hold a CPU-branch trace
+    scan = jax.jit(_decode_scan_fn(cfg, 16, False, 0), donate_argnums=(1,))
+    compiled = scan.lower(
+        params, caches, per_slot(jnp.int32), per_slot(jnp.int32),
+        per_slot(jnp.bool_), per_slot(jnp.float32), per_slot(jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip),
+    ).compile()
+
+    text = compiled.as_text()
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 1 and "taylor_decode_s2" in kernels[0], kernels
+    stack = "f32[28,1,6,2,128,128,128]"
+    assert stack in kernels[0]
+    layer_slice = re.compile(r"[a-z0-9]+\[(?:1,)*6,2,128,128,128\]")
+    touching = [line.strip()[:160] for line in text.splitlines()
+                if layer_slice.search(line)]
+    assert not touching, touching
+    whole_stack = [
+        line.strip()[:160] for line in text.splitlines()
+        if f"= {stack}" in line
+        and (" copy(" in line or 'custom_call_target="AllocateBuffer"' in line)
+    ]
+    assert not whole_stack, whole_stack
+    temp_gib = compiled.memory_analysis().temp_size_in_bytes / 2**30
+    assert temp_gib < 0.5, temp_gib
